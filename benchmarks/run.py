@@ -21,9 +21,8 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
   serve_*    — cross-query batched serving: a >= 32-strong same-fingerprint
                group through the ServeEngine vs sequential per-query calls,
-               the mixed read/write serving replay (qps + occupancy), and
-               the multi-device scaling curve (replay qps at 1/2/4 forced
-               host devices, DESIGN.md §12)
+               and the mixed read/write serving replay (qps + occupancy)
+  kernel_*   — the compiled Pallas block_spmm kernel (TPU only)
 
   online_*   — online self-funding view selection (DESIGN.md §13):
                measure-once fused builds vs the unfused Table III loop
@@ -623,50 +622,18 @@ def bench_serve(mode: str, seed: int) -> None:
          f"hoisted={rep.hoisted};share_rate={rep.share_rate:.2f};"
          f"deadline_misses={rep.deadline_misses}")
 
-    # -- multi-device scaling curve (DESIGN.md §12) -----------------------
-    # qps of the serving replay at 1/2/4 forced host devices.  Each point
-    # is a subprocess because XLA pins the host device count at first jax
-    # import.  On this 1-CPU-core container the forced "devices" are
-    # threads on one core, so qps *drops* with device count (shard_map
-    # overhead, no extra silicon) — the curve is an honest overhead
-    # measurement, and ``sharded_scaling_ratio`` (best multi-device qps /
-    # 1-device qps) is gated against the committed baseline so sharding
-    # overhead can't silently regress.  Row parity across device counts is
-    # asserted in ``tests/test_sharded.py``, not re-checked here.
-    import subprocess
-    env = dict(os.environ)
-    src_dir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src_dir + os.pathsep + env.get("PYTHONPATH", "")
-    qps_by_dev: dict = {}
-    for n_dev in (1, 2, 4):
-        cmd = [sys.executable, "-m", "benchmarks.workload_driver",
-               "--serve", "--dataset", "snb", "--small", "--clients", "8",
-               "--rounds", "2", "--seed", str(seed), "--no-sequential",
-               "--devices", str(n_dev)]
-        proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                              timeout=900)
-        assert proc.returncode == 0, (
-            f"scaling-curve leg --devices {n_dev} failed:\n"
-            + (proc.stdout + proc.stderr)[-2000:])
-        qps_line = [ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("QPS ")]
-        assert qps_line, f"no QPS line from --devices {n_dev}"
-        qps_by_dev[n_dev] = float(qps_line[-1].split()[1])
-    ratio = max(qps_by_dev[2], qps_by_dev[4]) / max(qps_by_dev[1], 1e-12)
-    _row("serve_sharded_scaling", 1e6 / max(qps_by_dev[4], 1e-12),
-         f"sharded_scaling_ratio={ratio:.3f};"
-         f"qps_dev1={qps_by_dev[1]:.1f};qps_dev2={qps_by_dev[2]:.1f};"
-         f"qps_dev4={qps_by_dev[4]:.1f}")
-
 
 def bench_kernels(mode: str, seed: int) -> None:
-    """Microbenchmarks of the Pallas kernels vs their jnp oracles
-    (interpret mode on CPU: correctness-path timing, not TPU perf)."""
+    """The compiled Pallas ``block_spmm`` kernel vs its jnp oracle.  Runs
+    only on a TPU: the kernel has no compiled CPU form, and an interpreted
+    timing says nothing about the chip."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
 
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise SystemExit(f"bench_kernels needs a TPU; found {platform!r}")
     rng = np.random.default_rng(seed)
     S = 256 if mode == "small" else 384
     F = jnp.asarray(rng.random((S, S)), jnp.float32)
@@ -681,12 +648,8 @@ def bench_kernels(mode: str, seed: int) -> None:
 
     t_ref = timeit(lambda: ref.block_spmm_ref(F, A, semiring="bool"))
     t_k = timeit(lambda: ops.block_spmm(F, A, counting=False))
-    _row("kernel_block_spmm_interp", t_k * 1e6, f"ref_us={t_ref*1e6:.1f}")
-
-    q = jnp.asarray(rng.standard_normal((1, 4, S, 64)), jnp.float32)
-    t_ref = timeit(lambda: ref.mha_ref(q, q, q, causal=True))
-    t_k = timeit(lambda: ops.flash_attention(q, q, q, causal=True))
-    _row("kernel_flash_attention_interp", t_k * 1e6, f"ref_us={t_ref*1e6:.1f}")
+    _row("kernel_block_spmm", t_k * 1e6,
+         f"ref_us={t_ref*1e6:.1f};device={jax.devices()[0].device_kind}")
 
 
 def bench_roofline(mode: str, seed: int) -> None:
@@ -995,6 +958,8 @@ def main() -> None:
     ap.add_argument("--json-dir", type=str, default="results",
                     help="directory for machine-readable BENCH_<name>.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     small = args.small or args.smoke
     mode = "small" if small else ("large" if args.large else "default")
     os.makedirs(args.json_dir, exist_ok=True)

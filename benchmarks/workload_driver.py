@@ -440,6 +440,8 @@ def main() -> None:
                     help="--serve only: skip the sequential twin replay "
                          "(faster; reports qps without speedup)")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.devices != _N_DEVICES:   # argparse and the early scan disagree
         raise SystemExit("--devices must be scannable from argv before "
                          "jax import; got inconsistent values")

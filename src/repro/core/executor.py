@@ -48,7 +48,7 @@ class ExecConfig:
     src_block: int = 256            # sources per frontier block
     max_closure_iters: int = 256    # safety bound for unbounded fixpoints
     use_pallas: bool = False        # route dense hops through the Pallas kernel
-    interpret: bool = True          # Pallas interpret mode (CPU container)
+    interpret: bool = False         # Pallas interpret mode (CPU tests only)
     collect_metrics: bool = True    # DBHit/Rows accounting (host syncs/hop)
     # --- compiled-plan (core/plan.py) knobs ------------------------------
     plan_backend: str = "auto"      # "auto" = per-hop cost-based choice;
@@ -512,6 +512,12 @@ class ExecEngine:
 
     def adj(self, label_id: int, counting: bool, reverse: bool,
             preds: Tuple[PropPred, ...] = ()) -> jax.Array:
+        n = self.g.node_cap
+        if n > self.cfg.dense_node_limit:
+            raise ValueError(
+                f"dense adjacency [{n}, {n}] int32 needs {4 * n * n} bytes; "
+                f"node_cap exceeds dense_node_limit={self.cfg.dense_node_limit}"
+                " — use segment hops (backend='segment', plan_backend='auto')")
         return self._lookup(
             self._adj_cache, (label_id, counting, reverse, preds), label_id,
             lambda: _dense_adjacency(self.g,

@@ -134,7 +134,11 @@ def _choose_backend(engine: ExecEngine, cfg: ExecConfig, label_id: int) -> str:
     adjacency is dense enough to keep the MXU busy.  We go dense (Pallas if
     enabled) when E_label / node_cap^2 >= ``cfg.dense_density`` and the tile
     fits (node_cap <= ``cfg.dense_node_limit``); ``cfg.plan_backend`` forces
-    a specific backend when not "auto".
+    a specific backend when not "auto".  Above the node limit "auto" and the
+    legacy ``backend="dense"`` override both stay on segment hops: a dense
+    ``[node_cap, node_cap]`` tile at an SNB-sized arena (63,488 nodes) is
+    16 GB.  A forced dense/pallas backend there fails in
+    :meth:`ExecEngine.adj` instead.
     """
     if cfg.data_shards > 1:
         # sharded execution partitions the per-label compact slices across
@@ -145,14 +149,14 @@ def _choose_backend(engine: ExecEngine, cfg: ExecConfig, label_id: int) -> str:
     mode = cfg.plan_backend
     if mode and mode != "auto":
         return mode
+    n = engine.g.node_cap
+    if n > cfg.dense_node_limit:
+        return "segment"
     if cfg.backend == "dense":
         # legacy global override: sessions configured with the unfused
         # executor's backend="dense" (+ use_pallas) keep forcing the dense
         # path; only the default "segment" defers to the cost model
         return "pallas" if cfg.use_pallas else "dense"
-    n = engine.g.node_cap
-    if n > cfg.dense_node_limit:
-        return "segment"
     e = engine.label_edge_count(label_id)
     if e >= cfg.dense_density * n * n:
         return "pallas" if cfg.use_pallas else "dense"
@@ -461,12 +465,11 @@ class CompiledPlan:
         source-id block is replicated.  F comes back reassembled
         ``[blk, N_pad]``; db/rows/ok are replicated (psum-reduced)."""
         from jax.sharding import PartitionSpec as P
-        from repro.utils import compat
         mesh = self.engine.mesh()
         col = P("data")
         in_specs = (P(None), col, col, col, col, P("data", None))
-        out_specs = (P(None, "data"), P(None), P(None), P(None))
-        return jax.jit(compat.shard_map(
+        out_specs = (P(None, "data"), P(None), P(None), P())
+        return jax.jit(jax.shard_map(
             self._program_sharded, mesh=mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False))
 
@@ -844,11 +847,10 @@ class SharedProgram:
         replicate; F returns column-assembled, metrics replicated.  Same
         mesh/spec scheme as :meth:`CompiledPlan._make_sharded_fn`."""
         from jax.sharding import PartitionSpec as P
-        from repro.utils import compat
         mesh = self.engine.mesh()
         in_specs = (P(None), P(None), P(None, "data"), P("data"))
-        out_specs = (P(None, "data"), P(None), P(None), P(None))
-        return jax.jit(compat.shard_map(
+        out_specs = (P(None, "data"), P(None), P(None), P())
+        return jax.jit(jax.shard_map(
             self._program_sharded, mesh=mesh, in_specs=in_specs,
             out_specs=out_specs, check_vma=False))
 
@@ -945,12 +947,11 @@ class SharedProgram:
         partials and closure convergence follow
         :meth:`CompiledPlan._program_sharded` exactly: one end-of-program
         psum, psum'd global frontier counts in the while_loop carry."""
-        from repro.utils import compat
         counting, collect = self.counting, self.collect
         blk = ids.shape[0]
         # masks shard to local columns; deg stays full-width ([1, M, N_pad])
         n_loc = (masks[0].shape[1] if masks
-                 else operands[0][0][4].shape[2] // compat.axis_size("data"))
+                 else operands[0][0][4].shape[2] // jax.lax.axis_size("data"))
         offset = jax.lax.axis_index("data") * n_loc
         lcol = ids - offset
         mine = (ids >= 0) & (lcol >= 0) & (lcol < n_loc)

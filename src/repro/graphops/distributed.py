@@ -25,15 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import compat
-
 
 def flat_axis_index(axes: Sequence[str]) -> jax.Array:
     """Linear shard index over a tuple of mesh axes (row-major, inside
     shard_map)."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -70,7 +68,7 @@ def dst_partitioned_aggregate(
         dst_local = dst_l - offset                           # [E_l] in-range
         return msg_and_reduce(h_full, src_l, dst_local, mask_l, n_loc)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec2, spec1, spec1, spec1),
         out_specs=spec2,

@@ -5,8 +5,8 @@
   flash_attention — fused online-softmax attention (LM prefill/decode)
 
 Each kernel ships with a pure-jnp oracle in ``ref.py`` and a jit'd public
-wrapper in ``ops.py``; tests sweep shapes/dtypes in interpret mode (this
-container is CPU-only; TPU is the compile target).
+wrapper in ``ops.py``.  Every wrapper compiles for the TPU by default; the
+CPU tests sweep shapes/dtypes with ``interpret=True``.
 """
 from repro.kernels import ops, ref
 

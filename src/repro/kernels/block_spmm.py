@@ -46,7 +46,7 @@ def _spmm_kernel(f_ref, a_ref, m_ref, o_ref, *, nk: int, semiring: str):
 def block_spmm(F: jax.Array, A: jax.Array, col_mask: jax.Array | None = None,
                *, semiring: str = "count", block_s: int = 128,
                block_n: int = 128, block_k: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool = False) -> jax.Array:
     """``semiring(F @ A) * col_mask`` with explicit VMEM tiling.
 
     F: [S, K] frontier counts/bool (any float/int dtype)

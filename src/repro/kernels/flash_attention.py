@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     "causal", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128, interpret: bool = False) -> jax.Array:
     """Fused attention forward.
 
     q: [B, H, Sq, D]; k, v: [B, H, Sk, D] (same H — expand GQA outside).

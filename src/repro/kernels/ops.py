@@ -13,7 +13,7 @@ from repro.utils import round_up
 
 
 def block_spmm(F: jax.Array, A: jax.Array, col_mask: jax.Array | None = None,
-               *, counting: bool = True, interpret: bool = True) -> jax.Array:
+               *, counting: bool = True, interpret: bool = False) -> jax.Array:
     """Semiring SpMM with automatic padding to MXU-aligned tiles."""
     S, K = F.shape
     _, N = A.shape
@@ -31,7 +31,7 @@ def block_spmm(F: jax.Array, A: jax.Array, col_mask: jax.Array | None = None,
 
 
 def segment_multi_agg(msg: jax.Array, valid: jax.Array, *,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Fused PNA aggregators with padding to tile-aligned shapes."""
     N, W, D = msg.shape
     Np = max(round_up(N, 8), 8)
@@ -42,7 +42,7 @@ def segment_multi_agg(msg: jax.Array, valid: jax.Array, *,
     return tuple(o[:N, :D] for o in outs)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = True,
+def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = False,
                     block_q: int = 128, block_k: int = 128):
     """GQA-aware flash attention: q [B,Hq,Sq,D], k/v [B,Hkv,Sk,D]."""
     B, Hq, Sq, D = q.shape

@@ -43,7 +43,7 @@ def _agg_kernel(msg_ref, valid_ref, mean_ref, max_ref, min_ref, std_ref,
                                              "interpret"))
 def segment_multi_agg(msg: jax.Array, valid: jax.Array, *, block_n: int = 8,
                       block_d: int = 128, eps: float = 1e-5,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """Fused (mean, max, min, std) over bucketed neighbor messages.
 
     msg:   [N, W, D]  padded neighbor messages

@@ -37,7 +37,7 @@ class TrainConfig:
     n_classes: int = 8
     n_layers: int = 2
     seed: int = 0
-    use_block_spmm: bool = False     # Pallas aggregation (interpret on CPU)
+    use_block_spmm: bool = False     # Pallas aggregation (compiled; TPU)
     drain: Optional[bool] = None     # None = view's freshness policy
 
 

@@ -17,8 +17,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils import compat
-
 NEG_INF = -1e30
 
 
@@ -130,7 +128,7 @@ def context_parallel_attention(q, k, v, mesh, *, data_axes=("data",),
                                  q_offset=m_idx * S_loc)
 
     spec = P(dspec, None, model_axis, None)
-    return compat.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
 

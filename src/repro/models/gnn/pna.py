@@ -17,8 +17,6 @@ from repro.graphops.segment import segment_mean
 from repro.models.common import Params, dense, dense_init, mlp, mlp_init
 from repro.models.gnn.graphdata import GraphBatch
 
-from repro.utils import compat
-
 
 @dataclass(frozen=True)
 class PNAConfig:
@@ -124,7 +122,7 @@ def _layer_sharded(lp, h, gb: GraphBatch, cfg: PNAConfig, delta: float):
         return _layer_local(lp_l, h_full, h_l, src_l, dst_local, emask_l,
                             nmask_l, n_loc, delta)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec2, spec1, spec1, spec1, spec1, P()),
         out_specs=spec2, check_vma=False,

@@ -30,7 +30,7 @@ class SAGEConfig:
     n_classes: int = 8
     n_layers: int = 2
     use_block_spmm: bool = False  # route aggregation through the Pallas SpMM
-    interpret: bool = True        # Pallas interpret mode (CPU-safe)
+    interpret: bool = False       # Pallas interpret mode (CPU tests only)
 
 
 def init_params(key, cfg: SAGEConfig) -> Params:

@@ -31,8 +31,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.common import Params
 from repro.models.moe import MoEConfig
 
-from repro.utils import compat
-
 
 def _local_dispatch(xt: jax.Array, router_w: jax.Array, cfg: MoEConfig,
                     C_loc: int):
@@ -147,7 +145,7 @@ def moe_apply_sharded(p: Params, x: jax.Array, cfg: MoEConfig, mesh,
         shared_specs = (P(), P(), P())
     x_spec = (P(dspec, model_axis, None) if cfg.seq_sharded
               else P(dspec, None, None))
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(x_spec, P(),

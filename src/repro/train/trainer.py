@@ -74,7 +74,7 @@ def make_compressed_dp_step(loss_fn, cfg: opt.AdamWConfig, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import shard_map
+    from jax import shard_map
 
     def local_step(params, opt_state, ef, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)

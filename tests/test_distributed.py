@@ -101,7 +101,7 @@ print("CP_ATTENTION_OK")
 
 # ---------------- compressed DP reduce ------------------------------------
 from repro.train.compression import compressed_psum
-from repro.utils.compat import shard_map
+from jax import shard_map
 def red(x):
     val, resid = compressed_psum(x, "data")
     return val, resid

@@ -1,4 +1,7 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles.
+
+Kernels default to compiling for the TPU; on the CPU every call here runs
+the Pallas interpreter (``interpret=True``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +22,8 @@ def test_block_spmm_matches_ref(shape, semiring, dtype):
     F = jnp.asarray(rng.integers(0, 3, (S, K)), dtype)
     A = jnp.asarray((rng.random((K, N)) < 0.2).astype(np.float32), dtype)
     mask = jnp.asarray(rng.integers(0, 2, (N,)).astype(np.float32))
-    got = ops.block_spmm(F, A, mask, counting=(semiring == "count"))
+    got = ops.block_spmm(F, A, mask, counting=(semiring == "count"),
+                         interpret=True)
     want = ref.block_spmm_ref(F, A, mask, semiring=semiring)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
@@ -28,7 +32,7 @@ def test_block_spmm_no_mask():
     rng = np.random.default_rng(0)
     F = jnp.asarray(rng.random((64, 64)), jnp.float32)
     A = jnp.asarray(rng.random((64, 64)), jnp.float32)
-    got = ops.block_spmm(F, A, counting=True)
+    got = ops.block_spmm(F, A, counting=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(F @ A), rtol=1e-5)
 
 
@@ -52,7 +56,7 @@ def test_block_spmm_hop_equivalence_with_executor():
                                                    src_block=32)).run_query(q)
     res_kernel = PathExecutor(
         g, schema, ExecConfig(backend="dense", src_block=32,
-                              use_pallas=True)).run_query(q)
+                              use_pallas=True, interpret=True)).run_query(q)
     np.testing.assert_array_equal(res_plain.reach, res_kernel.reach)
 
 
@@ -65,7 +69,7 @@ def test_segment_multi_agg_matches_ref(shape, dtype):
     rng = np.random.default_rng(hash(shape) % 2 ** 31)
     msg = jnp.asarray(rng.standard_normal((N, W, D)), dtype)
     valid = jnp.asarray(rng.random((N, W)) < 0.7)
-    got = ops.segment_multi_agg(msg, valid)
+    got = ops.segment_multi_agg(msg, valid, interpret=True)
     want = ref.segment_multi_agg_ref(msg.astype(jnp.float32), valid)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     for g_, w_ in zip(got, want):
@@ -76,7 +80,7 @@ def test_segment_multi_agg_matches_ref(shape, dtype):
 def test_segment_agg_empty_rows_are_zero():
     msg = jnp.ones((8, 4, 16), jnp.float32)
     valid = jnp.zeros((8, 4), bool)
-    for out in ops.segment_multi_agg(msg, valid):
+    for out in ops.segment_multi_agg(msg, valid, interpret=True):
         np.testing.assert_array_equal(np.asarray(out), 0.0)
 
 
@@ -89,7 +93,7 @@ def test_segment_agg_against_scatter_oracle():
     msg = rng.standard_normal((E, D)).astype(np.float32)
     bucketed, valid = ops.bucketize_messages(dst, msg, N)
     mean_k, *_ = ops.segment_multi_agg(jnp.asarray(bucketed),
-                                       jnp.asarray(valid))
+                                       jnp.asarray(valid), interpret=True)
     s = jops.segment_sum(jnp.asarray(msg), jnp.asarray(dst), N)
     cnt = jops.segment_sum(jnp.ones(E), jnp.asarray(dst), N)
     want = np.asarray(s) / np.maximum(np.asarray(cnt)[:, None], 1.0)
@@ -109,7 +113,7 @@ def test_flash_attention_matches_ref(shape, causal, dtype):
     q = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype) * 0.5
     k = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype) * 0.5
     v = jnp.asarray(rng.standard_normal((B, H, S, D)), dtype)
-    got = ops.flash_attention(q, k, v, causal=causal)
+    got = ops.flash_attention(q, k, v, causal=causal, interpret=True)
     want = ref.mha_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                        v.astype(jnp.float32), causal=causal)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
@@ -124,7 +128,7 @@ def test_flash_attention_gqa_expansion():
     q = jnp.asarray(rng.standard_normal((B, Hq, S, D)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True)
+    got = ops.flash_attention(q, k, v, causal=True, interpret=True)
     kr = jnp.repeat(k, Hq // Hkv, axis=1)
     vr = jnp.repeat(v, Hq // Hkv, axis=1)
     want = ref.mha_ref(q, kr, vr, causal=True)
@@ -139,7 +143,7 @@ def test_flash_attention_decode_offset():
     q = jnp.asarray(rng.standard_normal((B, H, Sq, D)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, H, Sk, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, H, Sk, D)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True)
+    got = ops.flash_attention(q, k, v, causal=True, interpret=True)
     want = ref.mha_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)

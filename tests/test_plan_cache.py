@@ -268,14 +268,32 @@ def test_legacy_backend_dense_forces_dense_plan():
                if isinstance(s, ExpandStep))
 
 
+def test_dense_hops_stay_off_above_the_node_limit():
+    """Above ``dense_node_limit`` the auto rule and the legacy dense
+    override both plan segment hops, and a forced dense backend refuses to
+    build the [node_cap, node_cap] tile."""
+    from repro.core.plan import ExpandStep
+    legacy = _toy_session(backend="dense", dense_node_limit=8)
+    want = legacy.query(QX, use_views=False)
+    plan = next(iter(legacy.planner._plans.values()))
+    assert all(s.backend == "segment" for s in plan.steps
+               if isinstance(s, ExpandStep))
+    np.testing.assert_array_equal(want.reach,
+                                  _toy_session().query(QX).reach)
+    forced = _toy_session(plan_backend="dense", dense_node_limit=8)
+    with pytest.raises(ValueError, match="dense_node_limit"):
+        forced.query(QX, use_views=False)
+
+
 def test_fused_plan_pallas_backend_parity():
     rng = np.random.default_rng(1)
     g, schema = _random_graph(rng, n=10, p=0.3)
     sess = GraphSession(g, schema, ExecConfig(src_block=16,
                                               plan_backend="pallas",
-                                              use_pallas=True))
+                                              use_pallas=True,
+                                              interpret=True))
     ex = PathExecutor(g, schema, ExecConfig(backend="dense", use_pallas=True,
-                                            src_block=16))
+                                            src_block=16, interpret=True))
     for q in ["MATCH (a:A)-[:x*1..2]->(b:B) RETURN a, b",
               "MATCH (a:A)-[:x*1..]->(b) RETURN a, b"]:
         res_p = sess.query(q, use_views=False)
