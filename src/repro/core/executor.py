@@ -38,6 +38,12 @@ from repro.core.pattern import (
 )
 from repro.core.schema import GraphSchema, NO_LABEL
 from repro.utils import INF_HOPS, round_up
+from repro.utils.trace import count, span, to_host
+
+# counter prefix of the graph session's device-to-host pulls outside the
+# fused plans (executor slice rebuilds and reach rows, view maintenance);
+# GraphSession.apply_writes counts those made in a fence as maint.to_host
+SESSION_PULLS = "session.to_host"
 
 
 @dataclass
@@ -446,12 +452,15 @@ class ExecEngine:
         if self._base_mask_cache is not None \
                 and self._base_mask_cache[0] == key:
             return self._base_mask_cache[1]
-        alive = np.asarray(self.g.edge_alive)
-        if self.schema.view_edge_ids:
-            base_ids = np.asarray(self.schema.base_edge_label_ids(), np.int32)
-            mask = alive & np.isin(np.asarray(self.g.edge_label), base_ids)
-        else:
-            mask = alive
+        with span("mv4pg.exec.slice_rebuild"):
+            alive = to_host(self.g.edge_alive, SESSION_PULLS)
+            if self.schema.view_edge_ids:
+                base_ids = np.asarray(self.schema.base_edge_label_ids(),
+                                      np.int32)
+                mask = alive & np.isin(to_host(self.g.edge_label,
+                                               SESSION_PULLS), base_ids)
+            else:
+                mask = alive
         self._base_mask_cache = (key, mask)
         return mask
 
@@ -459,14 +468,19 @@ class ExecEngine:
         """Compact slice + the arena edge ids behind it, in slice order (the
         ids align property columns with the slice for predicate masks)."""
         from repro.graphops.csr import compact_coo
-        if label_id == NO_LABEL:
-            keep = self._base_keep_mask()
-        else:
-            keep = (np.asarray(self.g.edge_alive)
-                    & (np.asarray(self.g.edge_label) == label_id))
-        src, dst, w, eids = compact_coo(self.g.edge_src, self.g.edge_dst,
-                                        self.g.edge_weight, keep)
-        return self._pack_slices(src, dst, w) + (eids,)
+        count("exec.slice_rebuilds")
+        with span("mv4pg.exec.slice_rebuild"):
+            g = self.g
+            if label_id == NO_LABEL:
+                keep = self._base_keep_mask()
+            else:
+                keep = (to_host(g.edge_alive, SESSION_PULLS)
+                        & (to_host(g.edge_label, SESSION_PULLS) == label_id))
+            src, dst, w, eids = compact_coo(
+                to_host(g.edge_src, SESSION_PULLS),
+                to_host(g.edge_dst, SESSION_PULLS),
+                to_host(g.edge_weight, SESSION_PULLS), keep)
+            return self._pack_slices(src, dst, w) + (eids,)
 
     def _edge_mask_for(self, label_id: int) -> jax.Array:
         """Arena-wide bool mask for ``label_id``; wildcard is base-only."""
@@ -833,7 +847,8 @@ class PathExecutor:
                 F = self._node_filter(
                     F, self.schema.node_label_id(nxt.label), nxt.key,
                     normalize_preds(nxt.preds))
-            out_rows.append(np.asarray(F))
+            with span("mv4pg.exec.to_host"):
+                out_rows.append(to_host(F, SESSION_PULLS))
         reach = np.concatenate(out_rows, axis=0)[:S].astype(np.int32)
         return ReachResult(src_ids=sources, reach=reach, counting=counting,
                            metrics=metrics)

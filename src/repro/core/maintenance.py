@@ -31,7 +31,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.executor import ExecConfig, Metrics, PathExecutor
+from repro.core.executor import (
+    SESSION_PULLS, ExecConfig, Metrics, PathExecutor,
+)
 from repro.core.graph import PropertyGraph, gathered_pred_mask
 from repro.core.pattern import (
     Direction, NodePat, PathPattern, PropPred, RelPat, ViewDef,
@@ -39,6 +41,7 @@ from repro.core.pattern import (
 )
 from repro.core.schema import GraphSchema, NO_LABEL
 from repro.utils import INF_HOPS
+from repro.utils.trace import to_host
 
 
 # ---------------------------------------------------------------------------
@@ -485,10 +488,10 @@ def batch_edge_delta_pairs(
         for U, V in orientations:
             if tpl.split is None:
                 if node_arrays is None:
-                    node_arrays = (np.asarray(ex_pre.g.node_label),
-                                   np.asarray(ex_pre.g.node_key),
-                                   np.asarray(ex_suf.g.node_label),
-                                   np.asarray(ex_suf.g.node_key))
+                    node_arrays = tuple(
+                        to_host(a, SESSION_PULLS) for a in (
+                            ex_pre.g.node_label, ex_pre.g.node_key,
+                            ex_suf.g.node_label, ex_suf.g.node_key))
                 pre_nl, pre_nk, suf_nl, suf_nk = node_arrays
                 keep = (_node_pat_mask(schema, vdef.match.nodes[tpl.position],
                                        U, pre_nl, pre_nk, ex_pre.g)
@@ -572,7 +575,7 @@ def affected_sources_nodes(templates: ViewTemplates, vdef: ViewDef,
     hit = np.zeros(ex.g.node_cap, bool)
     if node_ids.size == 0:
         return np.zeros(0, np.int32)
-    node_labels = np.asarray(ex.g.node_label)
+    node_labels = to_host(ex.g.node_label, SESSION_PULLS)
     for tpl in templates.node_delete:
         if tpl.node_label is not None:
             lid = schema.node_label_id(tpl.node_label)

@@ -88,6 +88,11 @@ from repro.core.pattern import (
 )
 from repro.core.schema import GraphSchema, NO_LABEL
 from repro.utils import INF_HOPS, round_up
+from repro.utils.trace import span, to_host
+
+
+# counter prefix of the fused programs' outputs copied to the host
+PLAN_PULLS = "plan.rows_to_host"
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +195,40 @@ def block_sizes(rows: int, blk: int, adaptive: bool) -> List[int]:
     while b < rows:
         b *= 2
     return [min(b, blk)]
+
+
+def _scope_name(max_hops: int, i: int) -> str:
+    """Name scope of the ``i``-th expand step (from 1) of a fused program:
+    ``hop<i>`` for a bounded hop range, ``closure<i>`` for an unbounded one,
+    so the device ops of a trace name the step they ran for."""
+    return f"{'closure' if max_hops == INF_HOPS else 'hop'}{i}"
+
+
+def _rows_to_host(outs: Sequence[tuple], R: int, node_cap: int
+                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Bring the launched blocks' ``(F, db, rows, ok)`` to the host: per
+    block, wait for its outputs, then copy them (so block k's copy overlaps
+    block k+1's compute), then concatenate.  Returns the first ``R`` rows of
+    the reach rows (cut to ``node_cap`` columns, int32) and of the per-row
+    DBHit/Rows vectors; raises if any closure did not converge."""
+    parts = []
+    for out in outs:
+        with span("mv4pg.plan.wait"):
+            jax.block_until_ready(out)
+        with span("mv4pg.plan.rows_to_host"):
+            parts.append([to_host(x, PLAN_PULLS) for x in out])
+    with span("mv4pg.plan.rows_to_host"):
+        F_h, db_h, rows_h, ok_h = zip(*parts)     # one block at least
+        # sharded F columns are padded to node_pad (a multiple of the shard
+        # count); slice back to the arena width — identity when unsharded
+        reach = np.concatenate(F_h, axis=0)[:R].astype(np.int32)
+        reach = reach[:, :node_cap]
+        db_vec = np.concatenate(db_h)[:R]
+        rows_vec = np.concatenate(rows_h)[:R]
+    if not all(bool(o) for o in ok_h):
+        raise RuntimeError(
+            "closure did not converge within max_closure_iters")
+    return reach, db_vec, rows_vec
 
 
 @dataclass
@@ -312,7 +351,7 @@ class CompiledPlan:
         elif cfg.data_shards > 1:
             self._fn = self._make_sharded_fn()
         else:
-            self._fn = jax.jit(self._program)
+            self._fn = jax.jit(self._program_plan)
 
     # -- validity ----------------------------------------------------------
 
@@ -331,8 +370,8 @@ class CompiledPlan:
 
     # -- fused program -----------------------------------------------------
 
-    def _program(self, ids, node_label, node_key, node_alive, nprops,
-                 operands):
+    def _program_plan(self, ids, node_label, node_key, node_alive,
+                      nprops, operands):
         """The whole query for one source block, as a single traced program.
 
         ``ids`` is the padded [blk] source-id block (-1 = padding); ``nprops``
@@ -405,54 +444,55 @@ class CompiledPlan:
                 continue
             step_ops = operands[op_i]
             op_i += 1
-            lo, hi = step.min_hops, step.max_hops
-            if hi != INF_HOPS:
-                # bounded: acc = sum/or over k in [lo, hi] (lo may be 0).
-                # Hops past an empty frontier contribute zero to F and both
-                # metrics, so skipping the host executor's early break is
-                # result- and metric-identical.
-                acc = F if lo == 0 else None
+            with jax.named_scope(_scope_name(step.max_hops, op_i)):
+                lo, hi = step.min_hops, step.max_hops
+                if hi != INF_HOPS:
+                    # bounded: acc = sum/or over k in [lo, hi] (lo may be 0).
+                    # Hops past an empty frontier contribute zero to F and both
+                    # metrics, so skipping the host executor's early break is
+                    # result- and metric-identical.
+                    acc = F if lo == 0 else None
+                    cur = F
+                    for k in range(1, hi + 1):
+                        cur, db, rows = hop(cur, step_ops, step.backend,
+                                            step.reverses, db, rows)
+                        if k >= lo:
+                            acc = cur if acc is None else (
+                                acc + cur if counting else acc | cur)
+                    F = acc if acc is not None else jnp.zeros_like(F)
+                    continue
+                # unbounded boolean closure as a device-side while loop
                 cur = F
-                for k in range(1, hi + 1):
+                for _ in range(max(lo, 0)):
                     cur, db, rows = hop(cur, step_ops, step.backend,
                                         step.reverses, db, rows)
-                    if k >= lo:
-                        acc = cur if acc is None else (
-                            acc + cur if counting else acc | cur)
-                F = acc if acc is not None else jnp.zeros_like(F)
-                continue
-            # unbounded boolean closure as a device-side while loop
-            cur = F
-            for _ in range(max(lo, 0)):
-                cur, db, rows = hop(cur, step_ops, step.backend,
-                                    step.reverses, db, rows)
 
-            def cond(c):
-                i, _reach, frontier, _db, _rows = c
-                return jnp.logical_and(i < self.cfg.max_closure_iters,
-                                       jnp.any(frontier))
+                def cond(c):
+                    i, _reach, frontier, _db, _rows = c
+                    return jnp.logical_and(i < self.cfg.max_closure_iters,
+                                           jnp.any(frontier))
 
-            def body(c):
-                i, reach, frontier, db, rows = c
-                nxt, db, rows = hop(frontier, step_ops, step.backend,
-                                    step.reverses, db, rows, skip_db=True)
-                return (i + 1, reach | nxt, nxt & ~reach, db, rows)
+                def body(c):
+                    i, reach, frontier, db, rows = c
+                    nxt, db, rows = hop(frontier, step_ops, step.backend,
+                                        step.reverses, db, rows, skip_db=True)
+                    return (i + 1, reach | nxt, nxt & ~reach, db, rows)
 
-            _, reach, frontier, db, rows = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), cur, cur, db, rows))
-            ok = ok & ~jnp.any(frontier)   # nonempty at exit: not converged
-            if collect:
-                # Successive closure frontiers are pairwise disjoint
-                # (frontier_{k+1} = nxt_k & ~reach_k) with union equal to the
-                # converged reach set, so the per-iteration DBHit sum
-                # telescopes to one matvec over ``reach`` — the same int32
-                # products summed in a different order, hoisted out of the
-                # while_loop where the [blk, N] cast dominated closure cost.
-                # A non-converged exit over-counts the residual frontier,
-                # but execute_rows raises before those metrics surface.
-                for arrs in step_ops:
-                    db = db + _hop_cost_per_source(reach, arrs[-1])
-            F = reach
+                _, reach, frontier, db, rows = jax.lax.while_loop(
+                    cond, body, (jnp.int32(0), cur, cur, db, rows))
+                ok = ok & ~jnp.any(frontier)   # nonempty at exit: not converged
+                if collect:
+                    # Successive closure frontiers are pairwise disjoint
+                    # (frontier_{k+1} = nxt_k & ~reach_k) with union equal to the
+                    # converged reach set, so the per-iteration DBHit sum
+                    # telescopes to one matvec over ``reach`` — the same int32
+                    # products summed in a different order, hoisted out of the
+                    # while_loop where the [blk, N] cast dominated closure cost.
+                    # A non-converged exit over-counts the residual frontier,
+                    # but execute_rows raises before those metrics surface.
+                    for arrs in step_ops:
+                        db = db + _hop_cost_per_source(reach, arrs[-1])
+                F = reach
         return F, db, rows, ok
 
     # -- sharded fused program (DESIGN.md §12) -----------------------------
@@ -477,7 +517,7 @@ class CompiledPlan:
                          operands):
         """Per-device body of the sharded fused program.
 
-        Same signature and step walk as :meth:`_program`, but node arrays
+        Same signature and step walk as :meth:`_program_plan`, but node arrays
         arrive as the shard's local column slice (``[n_loc]``), edge operands
         as the shard's dst-partition (leading shard axis of size 1), and F is
         the local column block ``[blk, n_loc]``.  Each hop all-gathers the
@@ -486,7 +526,7 @@ class CompiledPlan:
         scatters into the local column range only.  DBHit/Rows accumulate as
         per-shard partials (partial degree vectors / local-column row
         counts) and reduce with a **single psum** at program end, so
-        per-query metric parity with :meth:`_program` is exact — int32
+        per-query metric parity with :meth:`_program_plan` is exact — int32
         partial sums commute.  Unbounded closures carry a psum'd global
         frontier count so every shard agrees on the trip count."""
         counting = self.counting
@@ -571,7 +611,7 @@ class CompiledPlan:
                 cond, body, (jnp.int32(0), cur, cur, db, rows, act))
             ok = ok & (act == 0)
             if collect:
-                # disjoint-frontier telescoping (see _program): one matvec
+                # disjoint-frontier telescoping (see _program_plan): one matvec
                 # over the converged reach replaces the in-loop accumulation;
                 # per-device deg covers only the shard's edge partition, so
                 # the end-of-program psum still sums exact partials
@@ -665,47 +705,32 @@ class CompiledPlan:
         enables the serve-path power-of-two block sizing (the per-query path
         keeps fixed ``src_block`` blocks — see :func:`block_sizes`)."""
         g = self.engine.g
-        counts = [int(np.asarray(s).shape[0]) for s in source_lists]
-        R = sum(counts)
-        sizes = block_sizes(R, self.cfg.src_block, adaptive_blocks)
-        R_pad = sum(sizes)
-        padded = np.full(R_pad, -1, np.int32)
-        if R:
-            padded[:R] = np.concatenate(
-                [np.asarray(s, np.int32) for s in source_lists])
-        sharded = self.cfg.data_shards > 1
-        if sharded:
-            node_label, node_key, node_alive, nprops = \
-                self.engine.sharded_node_data(self._nprop_names)
-            operands = self._gather_operands_sharded()
-        else:
-            node_label, node_key, node_alive = (g.node_label, g.node_key,
-                                                g.node_alive)
-            nprops = tuple(g.node_prop_col(name)
-                           for name in self._nprop_names)
-            operands = self._gather_operands()
-
-        out_rows, db_parts, row_parts, ok_parts = [], [], [], []
-        b0 = 0
-        for blk in sizes:
-            F, db, rows, ok = self._fn(
-                jnp.asarray(padded[b0:b0 + blk]), node_label, node_key,
-                node_alive, nprops, operands)
-            out_rows.append(F)
-            db_parts.append(db)
-            row_parts.append(rows)
-            ok_parts.append(ok)
-            b0 += blk
-        reach = np.concatenate(
-            [np.asarray(F) for F in out_rows], axis=0)[:R].astype(np.int32)
-        # sharded F columns are padded to node_pad (multiple of the shard
-        # count); slice back to the arena width — identity when unsharded
-        reach = reach[:, :g.node_cap]
-        db_vec = np.concatenate([np.asarray(d) for d in db_parts])[:R]
-        rows_vec = np.concatenate([np.asarray(r) for r in row_parts])[:R]
-        if not all(bool(np.asarray(o)) for o in ok_parts):
-            raise RuntimeError(
-                "closure did not converge within max_closure_iters")
+        with span("mv4pg.plan.launch"):
+            counts = [int(np.asarray(s).shape[0]) for s in source_lists]
+            R = sum(counts)
+            sizes = block_sizes(R, self.cfg.src_block, adaptive_blocks)
+            padded = np.full(sum(sizes), -1, np.int32)
+            if R:
+                padded[:R] = np.concatenate(
+                    [np.asarray(s, np.int32) for s in source_lists])
+            if self.cfg.data_shards > 1:
+                node_label, node_key, node_alive, nprops = \
+                    self.engine.sharded_node_data(self._nprop_names)
+                operands = self._gather_operands_sharded()
+            else:
+                node_label, node_key, node_alive = (g.node_label, g.node_key,
+                                                    g.node_alive)
+                nprops = tuple(g.node_prop_col(name)
+                               for name in self._nprop_names)
+                operands = self._gather_operands()
+            outs = []
+            b0 = 0
+            for blk in sizes:
+                outs.append(self._fn(
+                    jnp.asarray(padded[b0:b0 + blk]), node_label, node_key,
+                    node_alive, nprops, operands))
+                b0 += blk
+        reach, db_vec, rows_vec = _rows_to_host(outs, R, g.node_cap)
         results: List[RowResult] = []
         off = 0
         for srcs, S in zip(source_lists, counts):
@@ -839,7 +864,7 @@ class SharedProgram:
         if data_shards > 1:
             self._fn = self._make_sharded_fn()
         else:
-            self._fn = jax.jit(self._program)
+            self._fn = jax.jit(self._program_shared)
 
     def _make_sharded_fn(self):
         """Sharded variant: masks column-shard over the data axis (members
@@ -856,12 +881,12 @@ class SharedProgram:
 
     # -- traced program ----------------------------------------------------
 
-    def _program(self, ids, midx, masks, operands):
+    def _program_shared(self, ids, midx, masks, operands):
         """One source block: ``ids`` [blk] (-1 padding), ``midx`` [blk]
         member indices, ``masks`` a tuple of [M, N] bool stacks (one per
         filter step), ``operands`` a tuple (one per expand step) of
         per-direction (src, dst, ew, emask, deg) stacks.  Mirrors
-        :meth:`CompiledPlan._program` with member-selected operands."""
+        :meth:`CompiledPlan._program_plan` with member-selected operands."""
         counting, collect = self.counting, self.collect
         blk = ids.shape[0]
         N = masks[0].shape[1] if masks else operands[0][0][4].shape[1]
@@ -885,58 +910,59 @@ class SharedProgram:
                 F = F & m if not counting else jnp.where(m, F, 0)
                 continue
             _, ndirs, lo, hi = sig
-            # member-select each direction's operands once per step; the
-            # hop closure (and the while_loop body) reuse the gathered rows
-            step_rows = tuple(
-                tuple(arr[midx] for arr in operands[oi][d])
-                for d in range(ndirs))
-            oi += 1
+            with jax.named_scope(_scope_name(hi, oi + 1)):
+                # member-select each direction's operands once per step; the
+                # hop closure (and the while_loop body) reuse the gathered rows
+                step_rows = tuple(
+                    tuple(arr[midx] for arr in operands[oi][d])
+                    for d in range(ndirs))
+                oi += 1
 
-            def hop(Fc, db, rows, step_rows=step_rows, skip_db=False):
-                out = None
-                for (a, b, ew, emask, deg) in step_rows:
-                    if collect and not skip_db:
-                        db = db + _hop_cost_rows(Fc, deg)
-                    nxt = _hop_segment_rows(Fc, a, b, emask, ew,
-                                            counting=counting)
-                    out = nxt if out is None else (
-                        out + nxt if counting else out | nxt)
-                if collect:
-                    rows = rows + _active_rows_per_source(out)
-                return out, db, rows
+                def hop(Fc, db, rows, step_rows=step_rows, skip_db=False):
+                    out = None
+                    for (a, b, ew, emask, deg) in step_rows:
+                        if collect and not skip_db:
+                            db = db + _hop_cost_rows(Fc, deg)
+                        nxt = _hop_segment_rows(Fc, a, b, emask, ew,
+                                                counting=counting)
+                        out = nxt if out is None else (
+                            out + nxt if counting else out | nxt)
+                    if collect:
+                        rows = rows + _active_rows_per_source(out)
+                    return out, db, rows
 
-            if hi != INF_HOPS:
-                acc = F if lo == 0 else None
+                if hi != INF_HOPS:
+                    acc = F if lo == 0 else None
+                    cur = F
+                    for k in range(1, hi + 1):
+                        cur, db, rows = hop(cur, db, rows)
+                        if k >= lo:
+                            acc = cur if acc is None else (
+                                acc + cur if counting else acc | cur)
+                    F = acc if acc is not None else jnp.zeros_like(F)
+                    continue
                 cur = F
-                for k in range(1, hi + 1):
+                for _ in range(max(lo, 0)):
                     cur, db, rows = hop(cur, db, rows)
-                    if k >= lo:
-                        acc = cur if acc is None else (
-                            acc + cur if counting else acc | cur)
-                F = acc if acc is not None else jnp.zeros_like(F)
-                continue
-            cur = F
-            for _ in range(max(lo, 0)):
-                cur, db, rows = hop(cur, db, rows)
 
-            def cond(c):
-                i, _reach, frontier, _db, _rows = c
-                return jnp.logical_and(i < self.max_closure_iters,
-                                       jnp.any(frontier))
+                def cond(c):
+                    i, _reach, frontier, _db, _rows = c
+                    return jnp.logical_and(i < self.max_closure_iters,
+                                           jnp.any(frontier))
 
-            def body(c):
-                i, reach, frontier, db, rows = c
-                nxt, db, rows = hop(frontier, db, rows, skip_db=True)
-                return (i + 1, reach | nxt, nxt & ~reach, db, rows)
+                def body(c):
+                    i, reach, frontier, db, rows = c
+                    nxt, db, rows = hop(frontier, db, rows, skip_db=True)
+                    return (i + 1, reach | nxt, nxt & ~reach, db, rows)
 
-            _, reach, frontier, db, rows = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), cur, cur, db, rows))
-            ok = ok & ~jnp.any(frontier)
-            if collect:
-                # disjoint-frontier telescoping (see CompiledPlan._program)
-                for (a, b, ew, emask, deg) in step_rows:
-                    db = db + _hop_cost_rows(reach, deg)
-            F = reach
+                _, reach, frontier, db, rows = jax.lax.while_loop(
+                    cond, body, (jnp.int32(0), cur, cur, db, rows))
+                ok = ok & ~jnp.any(frontier)
+                if collect:
+                    # disjoint-frontier telescoping (see CompiledPlan._program_plan)
+                    for (a, b, ew, emask, deg) in step_rows:
+                        db = db + _hop_cost_rows(reach, deg)
+                F = reach
         return F, db, rows, ok
 
     def _program_sharded(self, ids, midx, masks, operands):
@@ -1024,7 +1050,7 @@ class SharedProgram:
                 cond, body, (jnp.int32(0), cur, cur, db, rows, act))
             ok = ok & (act == 0)
             if collect:
-                # disjoint-frontier telescoping (see CompiledPlan._program)
+                # disjoint-frontier telescoping (see CompiledPlan._program_plan)
                 reach_full = jax.lax.all_gather(reach, "data", axis=1,
                                                 tiled=True)
                 for (a, b_local, ew, emask, deg) in step_rows:
@@ -1045,104 +1071,94 @@ class SharedProgram:
         with its member index.  Edge operands pad to the bucket's per-step
         maximum (padded edges are masked off → exact no-ops).  Returns
         per-plan lists of :class:`RowResult` matching ``spec_lists``."""
-        cfg = plans[0].cfg
-        eng = plans[0].engine
-        M = len(plans)
-        M_pad = 1 << max(M - 1, 1).bit_length()    # pow2 >= M, min 2
-        sharded = self.data_shards > 1
-        gathered = [p._gather_shared_operands_sharded() if sharded
-                    else p._gather_shared_operands() for p in plans]
+        with span("mv4pg.plan.launch"):
+            cfg = plans[0].cfg
+            eng = plans[0].engine
+            M = len(plans)
+            M_pad = 1 << max(M - 1, 1).bit_length()    # pow2 >= M, min 2
+            sharded = self.data_shards > 1
+            gathered = [p._gather_shared_operands_sharded() if sharded
+                        else p._gather_shared_operands() for p in plans]
 
-        n_filters = sum(1 for s in self.steps_sig if s[0] == "f")
-        masks_st = []
-        for fi in range(n_filters):
-            ms = [gathered[m][0][fi] for m in range(M)]
-            ms += [ms[0]] * (M_pad - M)
-            if sharded:     # host stack → one column-sharded device_put
-                masks_st.append(eng.shard_put_mask_stack(np.stack(ms)))
-            else:
-                masks_st.append(jnp.stack(ms))
+            n_filters = sum(1 for s in self.steps_sig if s[0] == "f")
+            masks_st = []
+            for fi in range(n_filters):
+                ms = [gathered[m][0][fi] for m in range(M)]
+                ms += [ms[0]] * (M_pad - M)
+                if sharded:     # host stack → one column-sharded device_put
+                    masks_st.append(eng.shard_put_mask_stack(np.stack(ms)))
+                else:
+                    masks_st.append(jnp.stack(ms))
 
-        ops_st = []
-        oi = 0
-        for sig in self.steps_sig:
-            if sig[0] != "x":
-                continue
-            ndirs = sig[1]
-            per_dir = []
-            for d in range(ndirs):
-                cols = [gathered[m][1][oi][d] for m in range(M)]
-                # edge widths pad to the pow2 ceiling of the bucket max —
-                # recurring shapes then hit the same XLA executable across
-                # windows (the warm pool's compile skip); members share a
-                # log2 scale, so inflation stays within the bucket's 2x
-                # bound (padded edges are masked — exact no-ops)
-                ax = 1 if sharded else 0     # sharded leaves are [D, Ep]
-                E_max = max(int(c[0].shape[ax]) for c in cols)
-                E = 1 << max(E_max - 1, 1).bit_length()
-                stacked = []
-                for j in range(5):          # src, dst, ew, emask, deg
-                    arrs = []
-                    for c in cols:
-                        a = c[j]
-                        if j < 4 and int(a.shape[ax]) < E:
-                            pad = (0, E - int(a.shape[ax]))
-                            if sharded:
-                                a = np.pad(a, ((0, 0), pad))
-                            else:
-                                a = jnp.pad(a, pad)
-                        arrs.append(a)
-                    arrs += [arrs[0]] * (M_pad - M)
-                    if sharded:   # [D, M_pad, ...], shard axis leading
-                        stacked.append(
-                            eng.shard_put_edges(np.stack(arrs, axis=1)))
-                    else:
-                        stacked.append(jnp.stack(arrs))
-                per_dir.append(tuple(stacked))
-            ops_st.append(tuple(per_dir))
-            oi += 1
-        masks_st = tuple(masks_st)
-        ops_st = tuple(ops_st)
+            ops_st = []
+            oi = 0
+            for sig in self.steps_sig:
+                if sig[0] != "x":
+                    continue
+                ndirs = sig[1]
+                per_dir = []
+                for d in range(ndirs):
+                    cols = [gathered[m][1][oi][d] for m in range(M)]
+                    # edge widths pad to the pow2 ceiling of the bucket max —
+                    # recurring shapes then hit the same XLA executable across
+                    # windows (the warm pool's compile skip); members share a
+                    # log2 scale, so inflation stays within the bucket's 2x
+                    # bound (padded edges are masked — exact no-ops)
+                    ax = 1 if sharded else 0     # sharded leaves are [D, Ep]
+                    E_max = max(int(c[0].shape[ax]) for c in cols)
+                    E = 1 << max(E_max - 1, 1).bit_length()
+                    stacked = []
+                    for j in range(5):          # src, dst, ew, emask, deg
+                        arrs = []
+                        for c in cols:
+                            a = c[j]
+                            if j < 4 and int(a.shape[ax]) < E:
+                                pad = (0, E - int(a.shape[ax]))
+                                if sharded:
+                                    a = np.pad(a, ((0, 0), pad))
+                                else:
+                                    a = jnp.pad(a, pad)
+                            arrs.append(a)
+                        arrs += [arrs[0]] * (M_pad - M)
+                        if sharded:   # [D, M_pad, ...], shard axis leading
+                            stacked.append(
+                                eng.shard_put_edges(np.stack(arrs, axis=1)))
+                        else:
+                            stacked.append(jnp.stack(arrs))
+                    per_dir.append(tuple(stacked))
+                ops_st.append(tuple(per_dir))
+                oi += 1
+            masks_st = tuple(masks_st)
+            ops_st = tuple(ops_st)
 
-        layout: List[Tuple[int, int, int]] = []   # (member, offset, S)
-        src_parts, midx_parts = [], []
-        off = 0
-        for m, specs in enumerate(spec_lists):
-            for s in specs:
-                arr = np.asarray(s, np.int32)
-                S = int(arr.shape[0])
-                layout.append((m, off, S))
-                src_parts.append(arr)
-                midx_parts.append(np.full(S, m, np.int32))
-                off += S
-        R = off
-        sizes = block_sizes(R, cfg.src_block, adaptive_blocks)
-        R_pad = sum(sizes)
-        ids = np.full(R_pad, -1, np.int32)
-        midx = np.zeros(R_pad, np.int32)
-        if R:
-            ids[:R] = np.concatenate(src_parts)
-            midx[:R] = np.concatenate(midx_parts)
+            layout: List[Tuple[int, int, int]] = []   # (member, offset, S)
+            src_parts, midx_parts = [], []
+            off = 0
+            for m, specs in enumerate(spec_lists):
+                for s in specs:
+                    arr = np.asarray(s, np.int32)
+                    S = int(arr.shape[0])
+                    layout.append((m, off, S))
+                    src_parts.append(arr)
+                    midx_parts.append(np.full(S, m, np.int32))
+                    off += S
+            R = off
+            sizes = block_sizes(R, cfg.src_block, adaptive_blocks)
+            R_pad = sum(sizes)
+            ids = np.full(R_pad, -1, np.int32)
+            midx = np.zeros(R_pad, np.int32)
+            if R:
+                ids[:R] = np.concatenate(src_parts)
+                midx[:R] = np.concatenate(midx_parts)
 
-        out_rows, db_parts, row_parts, ok_parts = [], [], [], []
-        b0 = 0
-        for blk in sizes:
-            F, db, rows, ok = self._fn(
-                jnp.asarray(ids[b0:b0 + blk]),
-                jnp.asarray(midx[b0:b0 + blk]), masks_st, ops_st)
-            out_rows.append(F)
-            db_parts.append(db)
-            row_parts.append(rows)
-            ok_parts.append(ok)
-            b0 += blk
-        reach = np.concatenate(
-            [np.asarray(F) for F in out_rows], axis=0)[:R].astype(np.int32)
-        reach = reach[:, :eng.g.node_cap]     # drop shard pad columns
-        db_vec = np.concatenate([np.asarray(d) for d in db_parts])[:R]
-        rows_vec = np.concatenate([np.asarray(r) for r in row_parts])[:R]
-        if not all(bool(np.asarray(o)) for o in ok_parts):
-            raise RuntimeError(
-                "closure did not converge within max_closure_iters")
+            outs = []
+            b0 = 0
+            for blk in sizes:
+                outs.append(self._fn(
+                    jnp.asarray(ids[b0:b0 + blk]),
+                    jnp.asarray(midx[b0:b0 + blk]), masks_st, ops_st))
+                b0 += blk
+        reach, db_vec, rows_vec = _rows_to_host(outs, R, eng.g.node_cap)
         results: List[List[RowResult]] = [[] for _ in plans]
         cursor = 0
         for (m, off, S) in layout:
@@ -1213,7 +1229,8 @@ class QueryPlanner:
                 self.rewrite_misses += 1
                 from repro.core.optimizer import optimize_query
                 t0 = time.perf_counter()
-                q_rw = optimize_query(q, list(views))
+                with span("mv4pg.plan.rewrite"):
+                    q_rw = optimize_query(q, list(views))
                 rewrite_s = time.perf_counter() - t0
                 self.rewrite_seconds_total += rewrite_s
                 path, force_bool = q_rw.path, q_rw.force_bool

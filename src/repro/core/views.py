@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core import graph as G
 from repro.core.executor import (
-    ExecConfig, ExecEngine, Metrics, PathExecutor, ReachResult,
+    SESSION_PULLS, ExecConfig, ExecEngine, Metrics, PathExecutor, ReachResult,
 )
 from repro.core.maintenance import (
     DeltaPairs, PendingDelta, ViewTemplates, affected_sources_edges,
@@ -44,6 +44,11 @@ from repro.core.pattern import FreshnessPolicy, Query, ViewDef
 from repro.core.plan import QueryPlanner
 from repro.core.schema import GraphSchema
 from repro.utils.deprecation import warn_once
+from repro.utils.trace import count, counters, span, to_host
+
+MAINT_BASE = "mv4pg.maint.base"      # base mutation steps of a fence
+MAINT_SWEEP = "mv4pg.maint.sweep"    # delta pairs and affected sources
+MAINT_APPLY = "mv4pg.maint.apply"    # writing the views' edges and index
 
 
 @dataclass
@@ -288,10 +293,10 @@ class GraphSession:
                             ) -> Tuple[G.PropertyGraph, np.ndarray]:
         """Reserve ``n`` free edge slots, growing the arena first if needed so
         growth cannot invalidate slots handed out earlier."""
-        free = np.flatnonzero(~np.asarray(g.edge_alive))
+        free = np.flatnonzero(~to_host(g.edge_alive, SESSION_PULLS))
         if free.shape[0] < n:
             g = G.grow_edge_arena(g, g.edge_cap + 2 * n + 128)
-            free = np.flatnonzero(~np.asarray(g.edge_alive))
+            free = np.flatnonzero(~to_host(g.edge_alive, SESSION_PULLS))
         return g, free[:n].astype(np.int32)
 
     def _reserve_node_slots(self, g: G.PropertyGraph, n: int
@@ -301,11 +306,11 @@ class GraphSession:
         Returns ``(graph, slots, grew)``.  Node growth changes ``node_cap``
         — the shape of frontiers, degree vectors and dense adjacency — so the
         caller must fully invalidate the engine when ``grew`` is True."""
-        free = np.flatnonzero(~np.asarray(g.node_alive))
+        free = np.flatnonzero(~to_host(g.node_alive, SESSION_PULLS))
         grew = False
         if free.shape[0] < n:
             g = G.grow_node_arena(g, g.node_cap + 2 * n + 128)
-            free = np.flatnonzero(~np.asarray(g.node_alive))
+            free = np.flatnonzero(~to_host(g.node_alive, SESSION_PULLS))
             grew = True
         return g, free[:n].astype(np.int32), grew
 
@@ -475,7 +480,8 @@ class GraphSession:
                                   np.asarray(upd_delta)),
                 {view.label_id})
             # drop dead pairs from the index
-            w = np.asarray(self.g.edge_weight)[np.asarray(upd_slots)]
+            w = to_host(self.g.edge_weight,
+                        SESSION_PULLS)[np.asarray(upd_slots)]
             for slot, wv in zip(upd_slots, w):
                 if wv <= 0:
                     s = int(self.g.edge_src[slot])
@@ -500,7 +506,7 @@ class GraphSession:
                 self.schema.node_label_id(start.label), start.key)
             if start.preds:
                 m = m & G.node_pred_mask(self.g, start.preds)
-            m_host = np.asarray(m)
+            m_host = to_host(m, SESSION_PULLS)
             run_sources = sources[m_host[sources]]
         if sources.size and run_sources.size:
             res = ex.run_path(view.vdef.match, counting=view.counting,
@@ -514,8 +520,8 @@ class GraphSession:
         upd_slots: List[int] = []
         upd_delta: List[int] = []
         # host copies once per recompute (no mutation until after the loop)
-        e_alive = np.asarray(self.g.edge_alive)
-        e_weight = np.asarray(self.g.edge_weight)
+        e_alive = to_host(self.g.edge_alive, SESSION_PULLS)
+        e_weight = to_host(self.g.edge_weight, SESSION_PULLS)
         for key in list(view.pair_slot.keys()):
             ms = key[0] if view.vdef.forward else key[1]  # match-start node
             if ms not in src_set:
@@ -598,119 +604,135 @@ class GraphSession:
         node deletes are handled by one batched affected-source recompute per
         view on the final graph.  Returns the assigned edge and node slots,
         in batch order.
+
+        The session's device-to-host pulls made in the fence
+        (``session.to_host_*``) are counted again as ``maint.to_host_*``.
         """
+        with span("mv4pg.maint.apply_writes"):
+            before = counters()
+            res = self._apply_writes(batch)
+            after = counters()
+            for k in ("_bytes", "_pulls"):
+                key = SESSION_PULLS + k
+                count("maint.to_host" + k,
+                      after.get(key, 0) - before.get(key, 0))
+            return res
+
+    def _apply_writes(self, batch: G.WriteBatch) -> BatchResult:
         metrics = Metrics()
-        self.write_epoch += 1
-        # exact maintenance telescopes around THIS batch from a consistent
-        # pre-state: any view maintained exactly this batch must first drain
-        # deltas queued while it ran under a non-exact routing
-        for view in list(self.views.values()):
-            if (self._effective_mode(view, batch) == "exact"
-                    and not view.pending.is_empty):
-                self._drain_view(view, metrics)
-        g0 = self.g
+        with span(MAINT_BASE):
+            self.write_epoch += 1
+            # exact maintenance telescopes around THIS batch from a consistent
+            # pre-state: any view maintained exactly this batch must first drain
+            # deltas queued while it ran under a non-exact routing
+            for view in list(self.views.values()):
+                if (self._effective_mode(view, batch) == "exact"
+                        and not view.pending.is_empty):
+                    self._drain_view(view, metrics)
+            g0 = self.g
 
-        # view edges are owned by the view machinery: a user-created edge
-        # carrying a view label would be invisible to wildcard queries, never
-        # maintained, and orphaned by drop_view — reject before mutating
-        for _, _, lbl in batch.edge_creates:
-            if self.schema.is_view_edge_label(lbl):
-                raise ValueError(
-                    f"cannot create a base edge with view label {lbl!r}; "
-                    f"view edges are maintained by create_view/apply_writes")
+            # view edges are owned by the view machinery: a user-created edge
+            # carrying a view label would be invisible to wildcard queries, never
+            # maintained, and orphaned by drop_view — reject before mutating
+            for _, _, lbl in batch.edge_creates:
+                if self.schema.is_view_edge_label(lbl):
+                    raise ValueError(
+                        f"cannot create a base edge with view label {lbl!r}; "
+                        f"view edges are maintained by create_view/apply_writes")
 
-        # -- resolve edge deletes against g0 (dedup; dead slots are no-ops)
-        e_alive0 = np.asarray(g0.edge_alive)
-        e_src0 = np.asarray(g0.edge_src)
-        e_dst0 = np.asarray(g0.edge_dst)
-        e_lab0 = np.asarray(g0.edge_label)
+            # -- resolve edge deletes against g0 (dedup; dead slots are no-ops)
+            e_alive0 = to_host(g0.edge_alive, SESSION_PULLS)
+            e_src0 = to_host(g0.edge_src, SESSION_PULLS)
+            e_dst0 = to_host(g0.edge_dst, SESSION_PULLS)
+            e_lab0 = to_host(g0.edge_label, SESSION_PULLS)
 
-        # view-edge property sets are rejected: view edges are derived state
-        # whose only legitimate mutation path is view maintenance.  (Deletes
-        # of view edges by arena id stay allowed — the established
-        # view-label-only-write escape hatch with zero maintenance work.)
-        for eid, prop, _ in batch.edge_prop_sets:
-            eid = int(eid)
-            if bool(e_alive0[eid]) \
-                    and self.schema.is_view_edge_label_id(int(e_lab0[eid])):
-                raise ValueError(
-                    f"cannot set property {prop!r} on edge {eid}: it is a "
-                    f"materialized view edge (maintained state)")
-        del_ids: List[int] = []
-        del_by_label: Dict[int, List[Tuple[int, int, int]]] = {}
-        seen = set()
-        for eid in batch.edge_deletes:
-            eid = int(eid)
-            if eid in seen or not bool(e_alive0[eid]):
-                continue
-            seen.add(eid)
-            del_ids.append(eid)
-            del_by_label.setdefault(int(e_lab0[eid]), []).append(
-                (int(e_src0[eid]), int(e_dst0[eid]), eid))
+            # view-edge property sets are rejected: view edges are derived state
+            # whose only legitimate mutation path is view maintenance.  (Deletes
+            # of view edges by arena id stay allowed — the established
+            # view-label-only-write escape hatch with zero maintenance work.)
+            for eid, prop, _ in batch.edge_prop_sets:
+                eid = int(eid)
+                if bool(e_alive0[eid]) \
+                        and self.schema.is_view_edge_label_id(int(e_lab0[eid])):
+                    raise ValueError(
+                        f"cannot set property {prop!r} on edge {eid}: it is a "
+                        f"materialized view edge (maintained state)")
+            del_ids: List[int] = []
+            del_by_label: Dict[int, List[Tuple[int, int, int]]] = {}
+            seen = set()
+            for eid in batch.edge_deletes:
+                eid = int(eid)
+                if eid in seen or not bool(e_alive0[eid]):
+                    continue
+                seen.add(eid)
+                del_ids.append(eid)
+                del_by_label.setdefault(int(e_lab0[eid]), []).append(
+                    (int(e_src0[eid]), int(e_dst0[eid]), eid))
 
-        # -- step 1: edge deletes  g0 -> g1
-        g1 = (G.delete_edges(g0, np.asarray(del_ids, np.int32))
-              if del_ids else g0)
+            # -- step 1: edge deletes  g0 -> g1
+            g1 = (G.delete_edges(g0, np.asarray(del_ids, np.int32))
+                  if del_ids else g0)
 
-        # -- step 2: edge creates  g1 -> g2 (reserve-then-grow)
-        create_by_label: Dict[int, List[int]] = {}
-        for j, (_, _, lbl) in enumerate(batch.edge_creates):
-            lid = self.schema.edge_labels.intern(lbl)
-            create_by_label.setdefault(lid, []).append(j)
-        g2 = g1
-        created_slots = np.zeros(0, np.int32)
-        if batch.edge_creates:
-            g2, created_slots = self._reserve_edge_slots(
-                g1, len(batch.edge_creates))
-            for lid, idxs in create_by_label.items():
-                g2 = G.create_edges(
-                    g2, created_slots[idxs],
-                    np.asarray([batch.edge_creates[j][0] for j in idxs],
-                               np.int32),
-                    np.asarray([batch.edge_creates[j][1] for j in idxs],
-                               np.int32),
-                    lid, np.ones(len(idxs), np.int32))
+            # -- step 2: edge creates  g1 -> g2 (reserve-then-grow)
+            create_by_label: Dict[int, List[int]] = {}
+            for j, (_, _, lbl) in enumerate(batch.edge_creates):
+                lid = self.schema.edge_labels.intern(lbl)
+                create_by_label.setdefault(lid, []).append(j)
+            g2 = g1
+            created_slots = np.zeros(0, np.int32)
+            if batch.edge_creates:
+                g2, created_slots = self._reserve_edge_slots(
+                    g1, len(batch.edge_creates))
+                for lid, idxs in create_by_label.items():
+                    g2 = G.create_edges(
+                        g2, created_slots[idxs],
+                        np.asarray([batch.edge_creates[j][0] for j in idxs],
+                                   np.int32),
+                        np.asarray([batch.edge_creates[j][1] for j in idxs],
+                                   np.int32),
+                        lid, np.ones(len(idxs), np.int32))
 
-        # -- step 3: node creates  g2 -> g2n (no maintenance; paper §IV-B)
-        g2n = g2
-        created_nodes = np.zeros(0, np.int32)
-        node_grew = False
-        if batch.node_creates:
-            g2, created_nodes, node_grew = self._reserve_node_slots(
-                g2, len(batch.node_creates))
-            g2n = G.create_nodes(
-                g2, created_nodes,
-                np.asarray([self.schema.node_labels.intern(lbl)
-                            for lbl, _ in batch.node_creates], np.int32),
-                np.asarray([int(created_nodes[i]) if k is None else int(k)
-                            for i, (_, k) in enumerate(batch.node_creates)],
-                           np.int32))
+            # -- step 3: node creates  g2 -> g2n (no maintenance; paper §IV-B)
+            g2n = g2
+            created_nodes = np.zeros(0, np.int32)
+            node_grew = False
+            if batch.node_creates:
+                g2, created_nodes, node_grew = self._reserve_node_slots(
+                    g2, len(batch.node_creates))
+                g2n = G.create_nodes(
+                    g2, created_nodes,
+                    np.asarray([self.schema.node_labels.intern(lbl)
+                                for lbl, _ in batch.node_creates], np.int32),
+                    np.asarray([int(created_nodes[i]) if k is None else int(k)
+                                for i, (_, k) in enumerate(batch.node_creates)],
+                               np.int32))
 
-        # -- step 4: node deletes  g2n -> g3 (kills incident edges too)
-        n_alive = np.asarray(g2n.node_alive)
-        node_del = np.unique(np.asarray(
-            [n for n in batch.node_deletes if bool(n_alive[int(n)])],
-            np.int32))
-        incident_labels: set = set()
-        # (label id, srcs, dsts) of edges killed by node deletes — captured
-        # BEFORE the delete so deferred queues record the broken endpoints
-        incident_groups: List[Tuple[int, np.ndarray, np.ndarray]] = []
-        g3 = g2n
-        if node_del.size:
-            e_alive2 = np.asarray(g2n.edge_alive)
-            dead = np.zeros(g2n.node_cap, bool)
-            dead[node_del] = True
-            inc = e_alive2 & (dead[np.asarray(g2n.edge_src)]
-                              | dead[np.asarray(g2n.edge_dst)])
-            inc_idx = np.flatnonzero(inc)
-            inc_lab = np.asarray(g2n.edge_label)[inc_idx]
-            inc_src = np.asarray(g2n.edge_src)[inc_idx]
-            inc_dst = np.asarray(g2n.edge_dst)[inc_idx]
-            for lid in np.unique(inc_lab):
-                m = inc_lab == lid
-                incident_groups.append((int(lid), inc_src[m], inc_dst[m]))
-            incident_labels = set(lid for lid, _, _ in incident_groups)
-            g3 = G.delete_nodes(g2n, node_del)
+            # -- step 4: node deletes  g2n -> g3 (kills incident edges too)
+            n_alive = to_host(g2n.node_alive, SESSION_PULLS)
+            node_del = np.unique(np.asarray(
+                [n for n in batch.node_deletes if bool(n_alive[int(n)])],
+                np.int32))
+            incident_labels: set = set()
+            # (label id, srcs, dsts) of edges killed by node deletes — captured
+            # BEFORE the delete so deferred queues record the broken endpoints
+            incident_groups: List[Tuple[int, np.ndarray, np.ndarray]] = []
+            g3 = g2n
+            if node_del.size:
+                e_alive2 = to_host(g2n.edge_alive, SESSION_PULLS)
+                e_src2 = to_host(g2n.edge_src, SESSION_PULLS)
+                e_dst2 = to_host(g2n.edge_dst, SESSION_PULLS)
+                dead = np.zeros(g2n.node_cap, bool)
+                dead[node_del] = True
+                inc = e_alive2 & (dead[e_src2] | dead[e_dst2])
+                inc_idx = np.flatnonzero(inc)
+                inc_lab = to_host(g2n.edge_label, SESSION_PULLS)[inc_idx]
+                inc_src = e_src2[inc_idx]
+                inc_dst = e_dst2[inc_idx]
+                for lid in np.unique(inc_lab):
+                    m = inc_lab == lid
+                    incident_groups.append((int(lid), inc_src[m], inc_dst[m]))
+                incident_labels = set(lid for lid, _, _ in incident_groups)
+                g3 = G.delete_nodes(g2n, node_del)
 
         if g3 is g0 and not batch.node_creates:
             # no structural change; property updates may still apply
@@ -720,33 +742,34 @@ class GraphSession:
             self.last_maintenance_metrics = metrics
             return BatchResult(created_slots, created_nodes)
 
-        # -- engine bookkeeping: snapshot the old side BEFORE swapping, then
-        # invalidate only the touched labels on the persistent engine
-        touched = set(del_by_label) | set(create_by_label) | incident_labels
-        old_eng = self.engine.snapshot()
-        # node-arena growth changes node_cap, invalidating every shape-keyed
-        # cache entry — fall back to full invalidation for this (rare) batch
-        self._set_graph(g3, None if node_grew else touched)
-        self._old_exec.engine = old_eng
-        # mid graph (after deletes, before creates): suffix side of both
-        # telescoping steps; coincides with an existing engine when possible
-        if g1 is g0:
-            mid_eng = old_eng
-        elif g1 is g3:
-            mid_eng = self.engine
-        else:
-            mid_eng = old_eng.snapshot(g1, set(del_by_label))
-        self._mid_exec.engine = mid_eng
-        # create-prefix side (after creates, before node deletes)
-        if node_del.size:
-            pre_eng = (old_eng if g2n is g0
-                       else self.engine.snapshot(g2n, incident_labels))
-        else:
-            pre_eng = self.engine
-        self._aux_exec.engine = pre_eng
+        with span(MAINT_BASE):
+            # -- engine bookkeeping: snapshot the old side BEFORE swapping, then
+            # invalidate only the touched labels on the persistent engine
+            touched = set(del_by_label) | set(create_by_label) | incident_labels
+            old_eng = self.engine.snapshot()
+            # node-arena growth changes node_cap, invalidating every shape-keyed
+            # cache entry — fall back to full invalidation for this (rare) batch
+            self._set_graph(g3, None if node_grew else touched)
+            self._old_exec.engine = old_eng
+            # mid graph (after deletes, before creates): suffix side of both
+            # telescoping steps; coincides with an existing engine when possible
+            if g1 is g0:
+                mid_eng = old_eng
+            elif g1 is g3:
+                mid_eng = self.engine
+            else:
+                mid_eng = old_eng.snapshot(g1, set(del_by_label))
+            self._mid_exec.engine = mid_eng
+            # create-prefix side (after creates, before node deletes)
+            if node_del.size:
+                pre_eng = (old_eng if g2n is g0
+                           else self.engine.snapshot(g2n, incident_labels))
+            else:
+                pre_eng = self.engine
+            self._aux_exec.engine = pre_eng
 
-        node_alive_final = np.asarray(g3.node_alive)
-        dead_set = {int(n) for n in node_del}
+            node_alive_final = to_host(g3.node_alive, SESSION_PULLS)
+            dead_set = {int(n) for n in node_del}
 
         def endpoints_alive(delta: DeltaPairs) -> DeltaPairs:
             """Drop delta rows whose view-pair endpoint died in this batch
@@ -779,9 +802,10 @@ class GraphSession:
                 # index purge stays synchronous for every policy: arena edges
                 # incident to deleted nodes are already dead, and leaving the
                 # slots indexed would alias recycled slots on the next create
-                for key in [k for k in view.pair_slot
-                            if k[0] in dead_set or k[1] in dead_set]:
-                    view.pair_slot.pop(key)
+                with span(MAINT_APPLY):
+                    for key in [k for k in view.pair_slot
+                                if k[0] in dead_set or k[1] in dead_set]:
+                        view.pair_slot.pop(key)
             if self._effective_mode(view, batch) != "exact":
                 # non-exact policies: the base mutations above already landed,
                 # so only this view's derived edges go stale.  Queue the
@@ -805,54 +829,65 @@ class GraphSession:
                 for name, srcs, dsts, eids in del_groups:
                     if not self._uses_label(view, name):
                         continue
-                    delta = batch_edge_delta_pairs(
-                        view.templates, view.vdef, self.schema, srcs, dsts,
-                        name, counting=True, metrics=metrics,
-                        ex_pre=self._old_exec, ex_suf=self._mid_exec,
-                        edge_ids=eids)
-                    self._apply_delta(view, endpoints_alive(delta), sign=-1)
+                    with span(MAINT_SWEEP):
+                        delta = batch_edge_delta_pairs(
+                            view.templates, view.vdef, self.schema, srcs,
+                            dsts, name, counting=True, metrics=metrics,
+                            ex_pre=self._old_exec, ex_suf=self._mid_exec,
+                            edge_ids=eids)
+                    with span(MAINT_APPLY):
+                        self._apply_delta(view, endpoints_alive(delta),
+                                          sign=-1)
                 for name, srcs, dsts, eids in create_groups:
                     if not self._uses_label(view, name):
                         continue
-                    delta = batch_edge_delta_pairs(
-                        view.templates, view.vdef, self.schema, srcs, dsts,
-                        name, counting=True, metrics=metrics,
-                        ex_pre=self._aux_exec, ex_suf=self._mid_exec,
-                        edge_ids=eids)
-                    self._apply_delta(view, endpoints_alive(delta), sign=+1)
+                    with span(MAINT_SWEEP):
+                        delta = batch_edge_delta_pairs(
+                            view.templates, view.vdef, self.schema, srcs,
+                            dsts, name, counting=True, metrics=metrics,
+                            ex_pre=self._aux_exec, ex_suf=self._mid_exec,
+                            edge_ids=eids)
+                    with span(MAINT_APPLY):
+                        self._apply_delta(view, endpoints_alive(delta),
+                                          sign=+1)
             else:
                 # set semantics: deletes delimit affected sources on the old
                 # graph; rows re-derive on the final graph below
                 for name, srcs, dsts, eids in del_groups:
                     if not self._uses_label(view, name):
                         continue
-                    aff = affected_sources_edges(
-                        view.templates, view.vdef, self.schema, srcs, dsts,
-                        name, metrics=metrics, ex=self._old_exec,
-                        edge_ids=eids)
+                    with span(MAINT_SWEEP):
+                        aff = affected_sources_edges(
+                            view.templates, view.vdef, self.schema, srcs,
+                            dsts, name, metrics=metrics, ex=self._old_exec,
+                            edge_ids=eids)
                     affected = np.union1d(affected, aff).astype(np.int32)
             if node_del.size:
-                aff = affected_sources_nodes(
-                    view.templates, view.vdef, self.schema, node_del,
-                    metrics=metrics, ex=self._aux_exec)
+                with span(MAINT_SWEEP):
+                    aff = affected_sources_nodes(
+                        view.templates, view.vdef, self.schema, node_del,
+                        metrics=metrics, ex=self._aux_exec)
                 affected = np.union1d(affected, aff).astype(np.int32)
             if affected.size:
                 affected = np.setdiff1d(affected, node_del).astype(np.int32)
             if affected.size:
-                self._recompute_sources(view, affected, metrics,
-                                        ex=self._delta)
+                with span(MAINT_APPLY):
+                    self._recompute_sources(view, affected, metrics,
+                                            ex=self._delta)
             if not view.counting:
                 # creates under set semantics: union-add pairs reachable
                 # through the new edges, evaluated on the final graph
                 for name, srcs, dsts, eids in create_groups:
                     if not self._uses_label(view, name):
                         continue
-                    delta = batch_edge_delta_pairs(
-                        view.templates, view.vdef, self.schema, srcs, dsts,
-                        name, counting=False, metrics=metrics,
-                        ex_pre=self._delta, ex_suf=self._delta,
-                        edge_ids=eids)
-                    self._apply_union(view, endpoints_alive(delta))
+                    with span(MAINT_SWEEP):
+                        delta = batch_edge_delta_pairs(
+                            view.templates, view.vdef, self.schema, srcs,
+                            dsts, name, counting=False, metrics=metrics,
+                            ex_pre=self._delta, ex_suf=self._delta,
+                            edge_ids=eids)
+                    with span(MAINT_APPLY):
+                        self._apply_union(view, endpoints_alive(delta))
             if (self.cfg.data_shards > 1
                     and (node_del.size
                          or any(self._uses_label(view, name)

@@ -63,7 +63,7 @@ from __future__ import annotations
 
 import collections
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import (TYPE_CHECKING, Deque, Dict, FrozenSet, List, Optional,
                     Tuple, Union)
 
@@ -76,6 +76,7 @@ from repro.core.parser import parse_query, query_fingerprint
 from repro.core.pattern import Query
 from repro.core.plan import CompiledPlan, ExpandStep, RowResult, block_sizes
 from repro.core.schema import NEVER_LABEL, NO_LABEL
+from repro.utils.trace import span
 
 if TYPE_CHECKING:  # pragma: no cover - typing only (avoids an import cycle)
     from repro.core.views import BatchResult, GraphSession
@@ -211,9 +212,7 @@ class ServeStats:
     rows: int = 0              # unique frontier rows packed into blocks
     blocks: int = 0            # fused device-program invocations
     block_capacity: int = 0    # total row slots launched
-    group_sizes: List[int] = field(default_factory=list)
-    window_sizes: List[int] = field(default_factory=list)  # tickets/window
-    block_sizes: List[int] = field(default_factory=list)   # slots/block
+    window_tickets: int = 0    # tickets selected into executed windows
     deadline_misses: int = 0   # tickets admitted after their deadline
     memo_hits: int = 0         # tickets answered from the cross-window memo
     gathers: int = 0           # tickets answered by row-subsumption gather
@@ -233,8 +232,7 @@ class ServeStats:
 
     @property
     def mean_window_size(self) -> float:
-        return (sum(self.window_sizes) / len(self.window_sizes)
-                if self.window_sizes else 0.0)
+        return self.window_tickets / self.windows if self.windows else 0.0
 
     @property
     def share_rate(self) -> float:
@@ -691,32 +689,35 @@ class ServeEngine:
         Returns False when the queue is drained."""
         if not self._queue:
             return False
-        window, resolved, embeds = self._collect()
-        for t, rr, via in resolved:
-            self._finish_read(t, rr, via)
-        if embeds:
-            self._run_embeds(embeds)
-        elif window:
-            window.sort(key=lambda e: (e[0].admit_by, e[0].uid))
-            selected = window[:self.window_limit]
-            self._run_window(selected)
-        elif not resolved:
-            if self._queue[0].kind != "write":
-                # unreachable: the front read has no fences ahead of it, so
-                # it is always eligible or memo-servable
-                raise RuntimeError("serve scheduler stalled with a pending "
-                                   f"read at the queue front "
-                                   f"(uid={self._queue[0].uid})")
-            self._apply_fence(self._queue.popleft())
-        self._queue = collections.deque(
-            t for t in self._queue if not t.done)
-        if self.selector is not None:
-            # quiescent point: the window ran (or the fence applied) and no
-            # in-flight plan references exist — catalog churn here honors
-            # the single-writer contract, and the next _collect re-plans
-            if self.selector.maybe_evaluate():
-                self.stats.auto_creates = self.selector.stats.creates
-                self.stats.auto_drops = self.selector.stats.drops
+        with span("mv4pg.serve.step"):
+            with span("mv4pg.serve.collect"):
+                window, resolved, embeds = self._collect()
+            for t, rr, via in resolved:
+                self._finish_read(t, rr, via)
+            if embeds:
+                self._run_embeds(embeds)
+            elif window:
+                window.sort(key=lambda e: (e[0].admit_by, e[0].uid))
+                selected = window[:self.window_limit]
+                self._run_window(selected)
+            elif not resolved:
+                if self._queue[0].kind != "write":
+                    # unreachable: the front read has no fences ahead of
+                    # it, so it is always eligible or memo-servable
+                    raise RuntimeError(
+                        "serve scheduler stalled with a pending read at the "
+                        f"queue front (uid={self._queue[0].uid})")
+                self._apply_fence(self._queue.popleft())
+            self._queue = collections.deque(
+                t for t in self._queue if not t.done)
+            if self.selector is not None:
+                # quiescent point: the window ran (or the fence applied) and
+                # no in-flight plan references exist — catalog churn here
+                # honors the single-writer contract, and the next _collect
+                # re-plans
+                if self.selector.maybe_evaluate():
+                    self.stats.auto_creates = self.selector.stats.creates
+                    self.stats.auto_drops = self.selector.stats.drops
         return True
 
     def run(self) -> ServeStats:
@@ -775,69 +776,71 @@ class ServeEngine:
         g_before = sess.g
         t0 = time.perf_counter()
 
-        groups: Dict[int, _Group] = {}
-        for t, plan, base in selected:
-            grp = groups.get(id(plan))
-            if grp is None:
-                grp = groups[id(plan)] = _Group(plan, base)
-            grp.tickets.append(t)
-            key = None if t.sources is None else t.sources.tobytes()
-            idx = grp.spec_idx.get(key)
-            if idx is None:
-                idx = len(grp.spec_sources)
-                grp.spec_idx[key] = idx
-                grp.spec_sources.append(
-                    plan.default_sources() if t.sources is None
-                    else t.sources)
-                if key is None:
-                    grp.unbound_idx = idx
-            grp.ticket_spec.append(idx)
+        with span("mv4pg.serve.group"):
+            groups: Dict[int, _Group] = {}
+            for t, plan, base in selected:
+                grp = groups.get(id(plan))
+                if grp is None:
+                    grp = groups[id(plan)] = _Group(plan, base)
+                grp.tickets.append(t)
+                key = None if t.sources is None else t.sources.tobytes()
+                idx = grp.spec_idx.get(key)
+                if idx is None:
+                    idx = len(grp.spec_sources)
+                    grp.spec_idx[key] = idx
+                    grp.spec_sources.append(
+                        plan.default_sources() if t.sources is None
+                        else t.sources)
+                    if key is None:
+                        grp.unbound_idx = idx
+                grp.ticket_spec.append(idx)
 
-        # split each group's specs into executed bindings and bindings
-        # answered by gathering rows of the group's unbound execution
-        plan_exec: Dict[int, List[int]] = {}      # group -> exec spec idxs
-        plan_gather: Dict[int, List[int]] = {}    # group -> gathered idxs
-        for gid, grp in groups.items():
-            ex, ga = [], []
-            ub = grp.unbound_idx
-            ub_src = grp.spec_sources[ub] if ub is not None else None
-            for i, src in enumerate(grp.spec_sources):
-                if (ub is not None and i != ub
-                        and _subset(src, ub_src)):
-                    ga.append(i)
-                else:
-                    ex.append(i)
-            plan_exec[gid] = ex
-            plan_gather[gid] = ga
-
-        # bucket groups by structure for cross-fingerprint sharing
-        buckets: Dict[tuple, List[int]] = {}
-        singles: List[int] = []
-        if cfg.structural_sharing:
-            if sess.view_set_generation != self._bucket_pool_gen:
-                # view-churn invalidation: drop warm shape keys learned
-                # under an older catalog so dropped-view shapes stop riding
-                # the pool and the pool can't grow without bound under churn
-                self._bucket_pool.clear()
-                self._bucket_pool_gen = sess.view_set_generation
+            # split each group's specs into executed bindings and bindings
+            # answered by gathering rows of the group's unbound execution
+            plan_exec: Dict[int, List[int]] = {}      # group -> exec spec idxs
+            plan_gather: Dict[int, List[int]] = {}    # group -> gathered idxs
             for gid, grp in groups.items():
-                skey = grp.plan.structure_key()
-                if skey is None:
-                    singles.append(gid)
-                else:
-                    bkey = (skey, grp.plan.share_scales())
-                    buckets.setdefault(bkey, []).append(gid)
-            for bkey, gids in list(buckets.items()):
-                if len(gids) < 2 and bkey not in self._bucket_pool:
-                    singles.extend(gids)
-                    del buckets[bkey]
-                else:
-                    self._bucket_pool.add(bkey)
-        else:
-            singles = list(groups)
+                ex, ga = [], []
+                ub = grp.unbound_idx
+                ub_src = grp.spec_sources[ub] if ub is not None else None
+                for i, src in enumerate(grp.spec_sources):
+                    if (ub is not None and i != ub
+                            and _subset(src, ub_src)):
+                        ga.append(i)
+                    else:
+                        ex.append(i)
+                plan_exec[gid] = ex
+                plan_gather[gid] = ga
 
-        spec_results: Dict[int, List[Optional[RowResult]]] = {
-            gid: [None] * len(groups[gid].spec_sources) for gid in groups}
+            # bucket groups by structure for cross-fingerprint sharing
+            buckets: Dict[tuple, List[int]] = {}
+            singles: List[int] = []
+            if cfg.structural_sharing:
+                if sess.view_set_generation != self._bucket_pool_gen:
+                    # view-churn invalidation: drop warm shape keys learned
+                    # under an older catalog so dropped-view shapes stop
+                    # riding the pool and the pool can't grow without bound
+                    # under churn
+                    self._bucket_pool.clear()
+                    self._bucket_pool_gen = sess.view_set_generation
+                for gid, grp in groups.items():
+                    skey = grp.plan.structure_key()
+                    if skey is None:
+                        singles.append(gid)
+                    else:
+                        bkey = (skey, grp.plan.share_scales())
+                        buckets.setdefault(bkey, []).append(gid)
+                for bkey, gids in list(buckets.items()):
+                    if len(gids) < 2 and bkey not in self._bucket_pool:
+                        singles.extend(gids)
+                        del buckets[bkey]
+                    else:
+                        self._bucket_pool.add(bkey)
+            else:
+                singles = list(groups)
+
+            spec_results: Dict[int, List[Optional[RowResult]]] = {
+                gid: [None] * len(groups[gid].spec_sources) for gid in groups}
 
         def account(n_rows: int) -> None:
             sizes = block_sizes(n_rows, sess.cfg.src_block,
@@ -845,7 +848,6 @@ class ServeEngine:
             st.rows += n_rows
             st.blocks += len(sizes)
             st.block_capacity += sum(sizes)
-            st.block_sizes.extend(sizes)
 
         for gid in singles:
             grp = groups[gid]
@@ -873,63 +875,63 @@ class ServeEngine:
             account(sum(int(np.asarray(s).shape[0])
                         for specs in spec_lists for s in specs))
 
-        for gid, grp in groups.items():
-            ub = grp.unbound_idx
-            for i in plan_gather[gid]:
-                spec_results[gid][i] = spec_results[gid][ub].gather(
-                    grp.spec_sources[i])
-            # memoize every binding's rows for cross-window reuse
-            if cfg.reuse_results:
-                for key, i in grp.spec_idx.items():
-                    self._memo[(grp.base, key)] = (grp.plan,
-                                                   spec_results[gid][i])
-            reach = [rr.to_reach_result() for rr in spec_results[gid]]
-            seen_specs = set()
-            for t, i in zip(grp.tickets, grp.ticket_spec):
-                t.result = reach[i]
-                t.window = self.epoch
-                t.window_seq = self._window_seq
-                if self.selector is not None and t.query is not None:
-                    self.selector.observe_read(t.query,
-                                               t.result.metrics.db_hits)
-                if i in plan_gather[gid]:
-                    t.via = "gather"
-                    st.gathers += 1
-                elif i in seen_specs:
-                    t.via = "dedup"
-                else:
-                    t.via = "exec"
-                seen_specs.add(i)
-                if t.window_seq > t.admit_by:
-                    st.deadline_misses += 1
-                if t.hoisted:
-                    st.hoisted += 1
-            st.groups += 1
-            st.queries += len(grp.tickets)
-            st.executions += len(plan_exec[gid])
-            st.group_sizes.append(len(grp.tickets))
+        with span("mv4pg.serve.finish"):
+            for gid, grp in groups.items():
+                ub = grp.unbound_idx
+                for i in plan_gather[gid]:
+                    spec_results[gid][i] = spec_results[gid][ub].gather(
+                        grp.spec_sources[i])
+                # memoize every binding's rows for cross-window reuse
+                if cfg.reuse_results:
+                    for key, i in grp.spec_idx.items():
+                        self._memo[(grp.base, key)] = (grp.plan,
+                                                       spec_results[gid][i])
+                reach = [rr.to_reach_result() for rr in spec_results[gid]]
+                seen_specs = set()
+                for t, i in zip(grp.tickets, grp.ticket_spec):
+                    t.result = reach[i]
+                    t.window = self.epoch
+                    t.window_seq = self._window_seq
+                    if self.selector is not None and t.query is not None:
+                        self.selector.observe_read(t.query,
+                                                   t.result.metrics.db_hits)
+                    if i in plan_gather[gid]:
+                        t.via = "gather"
+                        st.gathers += 1
+                    elif i in seen_specs:
+                        t.via = "dedup"
+                    else:
+                        t.via = "exec"
+                    seen_specs.add(i)
+                    if t.window_seq > t.admit_by:
+                        st.deadline_misses += 1
+                    if t.hoisted:
+                        st.hoisted += 1
+                st.groups += 1
+                st.queries += len(grp.tickets)
+                st.executions += len(plan_exec[gid])
 
-        # reads are pure: the window ran against one engine snapshot
-        assert sess.g is g_before, "a read mutated the session graph"
-        st.windows += 1
-        st.window_sizes.append(len(selected))
-        self._window_seq += 1
+            # reads are pure: the window ran against one engine snapshot
+            assert sess.g is g_before, "a read mutated the session graph"
+            st.windows += 1
+            st.window_tickets += len(selected)
+            self._window_seq += 1
 
-        # adaptive window limit: back off when per-ticket latency spikes,
-        # grow with queue depth (more waiting tickets -> bigger batches)
-        elapsed = time.perf_counter() - t0
-        per_ticket = elapsed / max(len(selected), 1)
-        depth = sum(1 for t in self._queue
-                    if t.kind == "read" and not t.done)
-        if (self._lat_ewma is not None
-                and per_ticket > cfg.latency_backoff * self._lat_ewma
-                and self.window_limit > cfg.window_min):
-            self.window_limit = max(cfg.window_min, self.window_limit // 2)
-        elif depth > self.window_limit:
-            self.window_limit = min(cfg.window_max, self.window_limit * 2)
-        a = cfg.latency_smoothing
-        self._lat_ewma = (per_ticket if self._lat_ewma is None
-                          else a * per_ticket + (1 - a) * self._lat_ewma)
+            # adaptive window limit: back off when per-ticket latency spikes,
+            # grow with queue depth (more waiting tickets -> bigger batches)
+            elapsed = time.perf_counter() - t0
+            per_ticket = elapsed / max(len(selected), 1)
+            depth = sum(1 for t in self._queue
+                        if t.kind == "read" and not t.done)
+            if (self._lat_ewma is not None
+                    and per_ticket > cfg.latency_backoff * self._lat_ewma
+                    and self.window_limit > cfg.window_min):
+                self.window_limit = max(cfg.window_min, self.window_limit // 2)
+            elif depth > self.window_limit:
+                self.window_limit = min(cfg.window_max, self.window_limit * 2)
+            a = cfg.latency_smoothing
+            self._lat_ewma = (per_ticket if self._lat_ewma is None
+                              else a * per_ticket + (1 - a) * self._lat_ewma)
 
     # --------------------------------------------------------------- fence
 

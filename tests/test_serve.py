@@ -321,7 +321,7 @@ def test_occupancy_counts_unique_rows():
     pts = [np.asarray([i], np.int32) for i in range(5)]
     tickets = [eng2.submit(q, sources=p) for p in pts]
     s2 = eng2.run()
-    assert s2.blocks == 1 and s2.block_sizes == [8], \
+    assert s2.blocks == 1 and s2.block_capacity == 8, \
         "5 point rows must pack one pow2-sized (8) block"
     assert s2.occupancy == 5 / 8
     for t, p in zip(tickets, pts):
