@@ -88,11 +88,15 @@ from repro.core.pattern import (
 )
 from repro.core.schema import GraphSchema, NO_LABEL
 from repro.utils import INF_HOPS, round_up
-from repro.utils.trace import span, to_host
+from repro.utils.trace import count, span, to_host, value
 
 
 # counter prefix of the fused programs' outputs copied to the host
 PLAN_PULLS = "plan.rows_to_host"
+# fused-program blocks dispatched, and at each wait for a block the blocks
+# dispatched after it (over ServeStats.blocks: the mean pipeline depth)
+BLOCKS_LAUNCHED = "plan.blocks_launched"
+BLOCKS_AHEAD = "plan.blocks_ahead"
 
 
 # ---------------------------------------------------------------------------
@@ -204,15 +208,28 @@ def _scope_name(max_hops: int, i: int) -> str:
     return f"{'closure' if max_hops == INF_HOPS else 'hop'}{i}"
 
 
-def _rows_to_host(outs: Sequence[tuple], R: int, node_cap: int
+def _start_copies(out: tuple) -> tuple:
+    """Start the device-to-host copy of one dispatched block's outputs, so it
+    runs while later blocks compute, and count the block as launched."""
+    for x in out:
+        x.copy_to_host_async()
+    count(BLOCKS_LAUNCHED)
+    return out
+
+
+def _rows_to_host(outs: Sequence[tuple], first_block: int, R: int,
+                  node_cap: int
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Bring the launched blocks' ``(F, db, rows, ok)`` to the host: per
-    block, wait for its outputs, then copy them (so block k's copy overlaps
-    block k+1's compute), then concatenate.  Returns the first ``R`` rows of
-    the reach rows (cut to ``node_cap`` columns, int32) and of the per-row
-    DBHit/Rows vectors; raises if any closure did not converge."""
+    block, wait for its outputs and take its copy (started at dispatch),
+    then concatenate.  ``first_block`` is the process-wide launch number of
+    ``outs[0]``; each wait counts the blocks launched after the awaited one
+    under ``plan.blocks_ahead``.  Returns the first ``R`` rows of the reach
+    rows (cut to ``node_cap`` columns, int32) and of the per-row DBHit/Rows
+    vectors; raises if any closure did not converge."""
     parts = []
-    for out in outs:
+    for k, out in enumerate(outs):
+        count(BLOCKS_AHEAD, value(BLOCKS_LAUNCHED) - (first_block + k) - 1)
         with span("mv4pg.plan.wait"):
             jax.block_until_ready(out)
         with span("mv4pg.plan.rows_to_host"):
@@ -274,6 +291,36 @@ class RowResult:
         idx = np.searchsorted(self.sources, sources)
         return RowResult(sources, self.reach[idx], self.db_vec[idx],
                          self.rows_vec[idx], self.counting)
+
+
+@dataclass
+class PendingRows:
+    """A fused execution's blocks in flight: what the launch half hands the
+    collect half.  Launching syncs nothing with the device, so a caller may
+    launch several executions before it collects the first."""
+
+    outs: List[tuple]          # per block (F, db, rows, ok), copies started
+    first_block: int           # process-wide launch number of outs[0]
+    sizes: List[int]           # block heights (row slots launched)
+    R: int                     # rows packed, padding excluded
+    node_cap: int
+    counting: bool
+    members: int
+    layout: List[Tuple[int, int, np.ndarray]]   # (member, row offset, ids)
+
+    def collect(self) -> List[List[RowResult]]:
+        """Wait for the blocks, take their rows, and split them per member
+        and binding in launch order; raises if a closure did not converge."""
+        reach, db_vec, rows_vec = _rows_to_host(self.outs, self.first_block,
+                                                self.R, self.node_cap)
+        results: List[List[RowResult]] = [[] for _ in range(self.members)]
+        for m, off, src in self.layout:
+            S = int(src.shape[0])
+            results[m].append(RowResult(
+                sources=src, reach=reach[off:off + S],
+                db_vec=db_vec[off:off + S], rows_vec=rows_vec[off:off + S],
+                counting=self.counting))
+        return results
 
 
 # ---------------------------------------------------------------------------
@@ -704,15 +751,26 @@ class CompiledPlan:
         and answer row-subsumed bindings by gathering.  ``adaptive_blocks``
         enables the serve-path power-of-two block sizing (the per-query path
         keeps fixed ``src_block`` blocks — see :func:`block_sizes`)."""
+        return self.launch_rows(source_lists,
+                                adaptive_blocks=adaptive_blocks).collect()[0]
+
+    def launch_rows(self, source_lists: Sequence[np.ndarray], *,
+                    adaptive_blocks: bool = False) -> PendingRows:
+        """The launch half of :meth:`execute_rows`: pad the sources, gather
+        the operands and dispatch every block, with each block's copy to the
+        host started; :meth:`PendingRows.collect` gives the results."""
         g = self.engine.g
         with span("mv4pg.plan.launch"):
-            counts = [int(np.asarray(s).shape[0]) for s in source_lists]
-            R = sum(counts)
+            srcs = [np.asarray(s, np.int32) for s in source_lists]
+            layout, off = [], 0
+            for s in srcs:
+                layout.append((0, off, s))
+                off += int(s.shape[0])
+            R = off
             sizes = block_sizes(R, self.cfg.src_block, adaptive_blocks)
             padded = np.full(sum(sizes), -1, np.int32)
             if R:
-                padded[:R] = np.concatenate(
-                    [np.asarray(s, np.int32) for s in source_lists])
+                padded[:R] = np.concatenate(srcs)
             if self.cfg.data_shards > 1:
                 node_label, node_key, node_alive, nprops = \
                     self.engine.sharded_node_data(self._nprop_names)
@@ -723,23 +781,16 @@ class CompiledPlan:
                 nprops = tuple(g.node_prop_col(name)
                                for name in self._nprop_names)
                 operands = self._gather_operands()
+            first = value(BLOCKS_LAUNCHED)
             outs = []
             b0 = 0
             for blk in sizes:
-                outs.append(self._fn(
+                outs.append(_start_copies(self._fn(
                     jnp.asarray(padded[b0:b0 + blk]), node_label, node_key,
-                    node_alive, nprops, operands))
+                    node_alive, nprops, operands)))
                 b0 += blk
-        reach, db_vec, rows_vec = _rows_to_host(outs, R, g.node_cap)
-        results: List[RowResult] = []
-        off = 0
-        for srcs, S in zip(source_lists, counts):
-            results.append(RowResult(
-                sources=np.asarray(srcs, np.int32),
-                reach=reach[off:off + S], db_vec=db_vec[off:off + S],
-                rows_vec=rows_vec[off:off + S], counting=self.counting))
-            off += S
-        return results
+        return PendingRows(outs, first, sizes, R, g.node_cap, self.counting,
+                           1, layout)
 
     # -- structural sharing ------------------------------------------------
 
@@ -1071,6 +1122,15 @@ class SharedProgram:
         with its member index.  Edge operands pad to the bucket's per-step
         maximum (padded edges are masked off → exact no-ops).  Returns
         per-plan lists of :class:`RowResult` matching ``spec_lists``."""
+        return self.launch(plans, spec_lists,
+                           adaptive_blocks=adaptive_blocks).collect()
+
+    def launch(self, plans: Sequence[CompiledPlan],
+               spec_lists: Sequence[Sequence[np.ndarray]], *,
+               adaptive_blocks: bool = True) -> PendingRows:
+        """The launch half of :meth:`execute`: stack the members' operands
+        and dispatch every block, with each block's copy to the host
+        started; :meth:`PendingRows.collect` gives the results."""
         with span("mv4pg.plan.launch"):
             cfg = plans[0].cfg
             eng = plans[0].engine
@@ -1131,14 +1191,14 @@ class SharedProgram:
             masks_st = tuple(masks_st)
             ops_st = tuple(ops_st)
 
-            layout: List[Tuple[int, int, int]] = []   # (member, offset, S)
+            layout: List[Tuple[int, int, np.ndarray]] = []
             src_parts, midx_parts = [], []
             off = 0
             for m, specs in enumerate(spec_lists):
                 for s in specs:
                     arr = np.asarray(s, np.int32)
                     S = int(arr.shape[0])
-                    layout.append((m, off, S))
+                    layout.append((m, off, arr))
                     src_parts.append(arr)
                     midx_parts.append(np.full(S, m, np.int32))
                     off += S
@@ -1151,23 +1211,16 @@ class SharedProgram:
                 ids[:R] = np.concatenate(src_parts)
                 midx[:R] = np.concatenate(midx_parts)
 
+            first = value(BLOCKS_LAUNCHED)
             outs = []
             b0 = 0
             for blk in sizes:
-                outs.append(self._fn(
+                outs.append(_start_copies(self._fn(
                     jnp.asarray(ids[b0:b0 + blk]),
-                    jnp.asarray(midx[b0:b0 + blk]), masks_st, ops_st))
+                    jnp.asarray(midx[b0:b0 + blk]), masks_st, ops_st)))
                 b0 += blk
-        reach, db_vec, rows_vec = _rows_to_host(outs, R, eng.g.node_cap)
-        results: List[List[RowResult]] = [[] for _ in plans]
-        cursor = 0
-        for (m, off, S) in layout:
-            results[m].append(RowResult(
-                sources=src_parts[cursor], reach=reach[off:off + S],
-                db_vec=db_vec[off:off + S], rows_vec=rows_vec[off:off + S],
-                counting=self.counting))
-            cursor += 1
-        return results
+        return PendingRows(outs, first, sizes, R, eng.g.node_cap,
+                           self.counting, M, layout)
 
 
 # ---------------------------------------------------------------------------

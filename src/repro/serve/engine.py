@@ -74,7 +74,7 @@ from repro.core.executor import ReachResult
 from repro.core.online_selection import OnlineSelectionConfig, OnlineSelector
 from repro.core.parser import parse_query, query_fingerprint
 from repro.core.pattern import Query
-from repro.core.plan import CompiledPlan, ExpandStep, RowResult, block_sizes
+from repro.core.plan import CompiledPlan, ExpandStep, RowResult
 from repro.core.schema import NEVER_LABEL, NO_LABEL
 from repro.utils.trace import span
 
@@ -842,38 +842,34 @@ class ServeEngine:
             spec_results: Dict[int, List[Optional[RowResult]]] = {
                 gid: [None] * len(groups[gid].spec_sources) for gid in groups}
 
-        def account(n_rows: int) -> None:
-            sizes = block_sizes(n_rows, sess.cfg.src_block,
-                                cfg.adaptive_blocks)
-            st.rows += n_rows
-            st.blocks += len(sizes)
-            st.block_capacity += sum(sizes)
-
+        # launch every group before collecting any: while the device runs
+        # group k, the host launches the groups after it, and block copies
+        # to the host run under the device work queued behind them
+        launched = []                         # (gids, shared?, pending)
         for gid in singles:
             grp = groups[gid]
-            ex = plan_exec[gid]
-            srcs = [grp.spec_sources[i] for i in ex]
-            rrs = grp.plan.execute_rows(srcs,
-                                        adaptive_blocks=cfg.adaptive_blocks)
-            for i, rr in zip(ex, rrs):
-                spec_results[gid][i] = rr
-            account(sum(int(np.asarray(s).shape[0]) for s in srcs))
-
+            srcs = [grp.spec_sources[i] for i in plan_exec[gid]]
+            launched.append(([gid], False, grp.plan.launch_rows(
+                srcs, adaptive_blocks=cfg.adaptive_blocks)))
         for (skey, _), gids in buckets.items():
             plans = [groups[gid].plan for gid in gids]
             spec_lists = [[groups[gid].spec_sources[i]
                            for i in plan_exec[gid]] for gid in gids]
             shared = sess.planner.shared_program(skey)
-            per_plan = shared.execute(plans, spec_lists,
-                                      adaptive_blocks=cfg.adaptive_blocks)
-            if len(gids) == 1:
-                st.warm_pool_hits += 1
-            for gid, rrs in zip(gids, per_plan):
+            launched.append((gids, True, shared.launch(
+                plans, spec_lists, adaptive_blocks=cfg.adaptive_blocks)))
+
+        for gids, is_shared, pending in launched:
+            for gid, rrs in zip(gids, pending.collect()):
                 for i, rr in zip(plan_exec[gid], rrs):
                     spec_results[gid][i] = rr
-                st.shared_groups += 1
-            account(sum(int(np.asarray(s).shape[0])
-                        for specs in spec_lists for s in specs))
+            if is_shared:
+                st.shared_groups += len(gids)
+                if len(gids) == 1:
+                    st.warm_pool_hits += 1
+            st.rows += pending.R
+            st.blocks += len(pending.sizes)
+            st.block_capacity += sum(pending.sizes)
 
         with span("mv4pg.serve.finish"):
             for gid, grp in groups.items():

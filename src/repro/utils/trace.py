@@ -40,6 +40,11 @@ def count(name: str, n: int = 1) -> None:
     _counts[name] = _counts.get(name, 0) + n
 
 
+def value(name: str) -> int:
+    """One counter's value (0 before its first add)."""
+    return _counts.get(name, 0)
+
+
 def counters() -> Dict[str, int]:
     """A snapshot of every counter."""
     return dict(_counts)

@@ -432,3 +432,67 @@ def test_predicate_view_rewrite_parity_through_plan():
         "equal-predicate query must rewrite through the predicate view"
     assert (_pairs(sess.query(q, use_views=True))
             == _pairs(sess.query(q, use_views=False)))
+
+
+# ---------------------------------------------------------------------------
+# launch / collect halves
+# ---------------------------------------------------------------------------
+
+Q_SX = "MATCH (a:A)-[:x]->(b) RETURN a, b"
+Q_SY = "MATCH (s:B)-[:y]->(d) RETURN s, d"
+
+
+def _same_rows(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.sources, w.sources)
+        np.testing.assert_array_equal(g.reach, w.reach)
+        np.testing.assert_array_equal(g.db_vec, w.db_vec)
+        np.testing.assert_array_equal(g.rows_vec, w.rows_vec)
+        assert g.counting == w.counting
+
+
+def test_launch_then_collect_equals_execute():
+    """Both fused executors launched before either is collected give what
+    ``execute_rows`` / ``SharedProgram.execute`` give; the launches pull
+    nothing to the host, and every wait counts the blocks launched after
+    the one it waits for."""
+    from repro.core.plan import BLOCKS_AHEAD, BLOCKS_LAUNCHED, PLAN_PULLS
+    from repro.utils import trace
+    sess = _toy_session(src_block=4, plan_backend="segment")
+
+    def plan(q):
+        return sess.planner.plan(parse_query(q), [], 0)[0]
+
+    p_qx, p_sx, p_sy = plan(QX), plan(Q_SX), plan(Q_SY)
+    assert p_sx.structure_key() == p_sy.structure_key()
+    shared = sess.planner.shared_program(p_sx.structure_key())
+    srcs = [np.arange(8, dtype=np.int32), np.array([0, 2], np.int32)]
+    specs = [[np.arange(8, dtype=np.int32)],
+             [np.array([1, 3], np.int32), np.array([5], np.int32)]]
+    pulls = PLAN_PULLS + "_pulls"
+
+    c0 = trace.counters()
+    want_rows = p_qx.execute_rows(srcs)
+    want_shared = shared.execute([p_sx, p_sy], specs)
+    c1 = trace.counters()
+    # run alone, each execution waits with only its own blocks behind
+    assert c1[BLOCKS_LAUNCHED] - c0.get(BLOCKS_LAUNCHED, 0) == 3 + 3
+    assert c1[BLOCKS_AHEAD] - c0.get(BLOCKS_AHEAD, 0) == 2 * (2 + 1 + 0)
+
+    pend_rows = p_qx.launch_rows(srcs)
+    pend_shared = shared.launch([p_sx, p_sy], specs)
+    c2 = trace.counters()
+    assert (pend_rows.sizes, pend_shared.sizes) == ([4] * 3, [4] * 3)
+    assert c2[BLOCKS_LAUNCHED] - c1[BLOCKS_LAUNCHED] == 6
+    assert c2.get(pulls, 0) == c1.get(pulls, 0), "a launch pulled rows"
+    assert c2.get(BLOCKS_AHEAD, 0) == c1[BLOCKS_AHEAD]
+    got_rows = pend_rows.collect()
+    assert trace.value(pulls) - c2[pulls] == 4 * 3
+    got_shared = pend_shared.collect()
+    # six blocks in flight: the k-th wait has 5 - k launched behind it
+    assert trace.value(BLOCKS_AHEAD) - c2[BLOCKS_AHEAD] == 5 + 4 + 3 + 2 + 1
+    assert len(got_rows) == 1 and len(got_shared) == 2
+    _same_rows(got_rows[0], want_rows)
+    for got, want in zip(got_shared, want_shared):
+        _same_rows(got, want)

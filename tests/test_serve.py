@@ -13,6 +13,7 @@ starve older tickets, and structural sharing / gather / memo answers are
 bit-identical to solo execution.
 """
 import numpy as np
+import pytest
 
 from repro.core import GraphBuilder, GraphSchema, GraphSession, WriteBatch
 from repro.serve.engine import ServeConfig
@@ -545,3 +546,96 @@ def test_view_churn_under_traffic_stays_consistent():
     _assert_same(b.result, seq_sess.query(QUERIES[0]), "post-churn rows")
     assert eng._bucket_pool_gen == serve_sess.view_set_generation, \
         "first window after churn must reset the warm pool generation"
+
+
+def test_pipelined_window_matches_groups_run_alone():
+    """A window of two singles and one shared bucket launches all three
+    before collecting any; its rows, metrics and counts equal each group
+    run by itself (``execute_rows`` / ``SharedProgram.execute`` for the
+    rows, an engine of its own for the counts)."""
+    from repro.core.parser import parse_query
+    from repro.core.plan import BLOCKS_AHEAD
+    from repro.utils import trace
+    sess = _build(seed=9)
+    q_x = "MATCH (a:A)-[e:x]->(b) RETURN a, b"
+    q_y = "MATCH (s:B)-[e:y]->(d) RETURN s, d"
+    singles = [QUERIES[0], QUERIES[2]]   # two hops; an unbounded closure
+    reads = singles + [q_x, q_y]
+    pts = {q: [np.asarray([i], np.int32) for i in range(k, 14, 3)]
+           for k, q in enumerate(reads)}
+
+    def plan(q):
+        return sess.planner.plan(parse_query(q), [], 0)[0]
+
+    eng = sess.serve()
+    tickets = {q: [eng.submit(q, sources=p) for p in pts[q]] for q in reads}
+    a0 = trace.value(BLOCKS_AHEAD)
+    stats = eng.run()
+    assert stats.windows == 1 and stats.groups == 4
+    assert stats.shared_groups == 2
+    # three one-block launches in flight: two, then one, then none behind
+    assert stats.blocks == 3
+    assert trace.value(BLOCKS_AHEAD) - a0 == 2 + 1
+
+    for q in singles:
+        want = plan(q).execute_rows(pts[q], adaptive_blocks=True)
+        for t, rr in zip(tickets[q], want):
+            _assert_same(t.result, rr.to_reach_result(), q)
+    p_x, p_y = plan(q_x), plan(q_y)
+    shared = sess.planner.shared_program(p_x.structure_key())
+    per_plan = shared.execute([p_x, p_y], [pts[q_x], pts[q_y]])
+    for q, want in zip((q_x, q_y), per_plan):
+        for t, rr in zip(tickets[q], want):
+            _assert_same(t.result, rr.to_reach_result(), q)
+
+    alone = []
+    for group in ([QUERIES[0]], [QUERIES[2]], [q_x, q_y]):
+        e = sess.serve()
+        for q in group:
+            for p in pts[q]:
+                e.submit(q, sources=p)
+        alone.append(e.run())
+    for field in ("queries", "groups", "executions", "rows", "blocks",
+                  "block_capacity", "shared_groups", "warm_pool_hits"):
+        assert getattr(stats, field) == sum(getattr(s, field)
+                                            for s in alone), field
+
+
+def test_unconverged_group_raises_after_every_launch():
+    """A closure that cannot converge (``max_closure_iters=1`` on a cycle),
+    launched after a good group in the same window, still raises; both
+    groups were launched first, and once the bound is back the engine
+    serves its tickets and a next window exactly."""
+    from repro.core.plan import BLOCKS_AHEAD, BLOCKS_LAUNCHED
+    from repro.utils import trace
+    schema = GraphSchema()
+    b = GraphBuilder(schema)
+    for i in range(6):
+        b.add_node(("A", "B")[i % 2])
+    for i in range(6):
+        b.add_edge(i, (i + 1) % 6, "x")     # a cycle: reach grows 5 hops
+        b.add_edge(i, (i + 3) % 6, "y")
+    sess = GraphSession(b.finalize(), schema)
+    good, bad = QUERIES[0], QUERIES[2]
+    src = np.asarray([0], np.int32)
+    sess.cfg.max_closure_iters = 1
+    eng = sess.serve()
+    t_good, t_bad = eng.submit(good, sources=src), eng.submit(bad,
+                                                              sources=src)
+    c0 = trace.counters()
+    with pytest.raises(RuntimeError, match="did not converge"):
+        eng.step()
+    c1 = trace.counters()
+    assert c1[BLOCKS_LAUNCHED] - c0.get(BLOCKS_LAUNCHED, 0) == 2
+    assert c1[BLOCKS_AHEAD] - c0.get(BLOCKS_AHEAD, 0) == 1
+    assert not t_good.done and not t_bad.done
+    assert eng.stats.windows == 0
+
+    sess.cfg.max_closure_iters = 256
+    eng.run()
+    more = [eng.submit(q, sources=np.asarray([i], np.int32))
+            for q in (good, bad) for i in (2, 4)]
+    eng.run()
+    assert eng.stats.windows == 2
+    for t in [t_good, t_bad] + more:
+        _assert_same(t.result, sess.query(t.query, sources=t.sources))
