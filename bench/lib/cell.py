@@ -347,32 +347,82 @@ def _graph_host(g) -> dict:
              "edge_weight", "node_label", "node_alive")}
 
 
-def _view_pairs_stored(h: dict, label_id: int, counting: bool):
+def _n_slots(h: dict, model) -> int:
+    """The node id space of both sides: every id either can hold."""
+    return max(h["node_label"].shape[0], model.n)
+
+
+def _view_pairs_stored(h: dict, label_id: int, counting: bool, n_slots: int
+                       ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """The view's alive arena edges as sorted ``pair_keys`` and counts
+    (the weight of counting views, 1 otherwise), and how many pairs are
+    stored more than once.  Of a pair stored twice the entry in the later
+    slot stands, as the last one written into a dict would."""
     m = h["edge_alive"] & (h["edge_label"] == label_id)
-    w = h["edge_weight"][m] if counting else np.ones(int(m.sum()), np.int64)
-    return {(int(a), int(b)): int(c) for a, b, c in
-            zip(h["edge_src"][m], h["edge_dst"][m], w)}
+    keys = ref_mod.pair_keys(h["edge_src"][m], h["edge_dst"][m], n_slots)
+    counts = (h["edge_weight"][m].astype(np.int64) if counting
+              else np.ones(keys.shape[0], np.int64))
+    order = np.argsort(keys, kind="stable")
+    keys, counts = keys[order], counts[order]
+    new = keys[1:] != keys[:-1]
+    first, last = np.ones((2, keys.shape[0]), bool)
+    first[1:] = last[:-1] = new
+    return keys[last], counts[last], int(np.count_nonzero(first & ~last))
 
 
-def _dict_diff(a: dict, b: dict) -> int:
-    return sum(1 for k in set(a) | set(b) if a.get(k) != b.get(k))
+def _key_diff(a: Tuple[np.ndarray, np.ndarray],
+              b: Tuple[np.ndarray, np.ndarray]) -> int:
+    """How many keys of the union have a different count on the two sides,
+    a key on one side alone counting; each side is (sorted distinct int64
+    keys, counts)."""
+    (ka, ca), (kb, cb) = a, b
+    at = np.searchsorted(kb, ka)
+    hit = at < kb.shape[0]
+    hit[hit] = kb[at[hit]] == ka[hit]
+    same = int(np.count_nonzero(ca[hit] == cb[at[hit]]))
+    return ka.shape[0] + kb.shape[0] - int(np.count_nonzero(hit)) - same
+
+
+def _label_ids(registry, names: np.ndarray) -> Tuple[np.ndarray, int]:
+    """The program's ids of label ``names`` (an object array), each name
+    looked up once; a name the program never interned gets an id of its own
+    past the program's.  Returns the ids and the size of the id space."""
+    uniq, inv = np.unique(names, return_inverse=True)
+    ids = np.empty(uniq.shape[0], np.int64)
+    n = len(registry)
+    for i, name in enumerate(uniq.tolist()):
+        if name in registry:
+            ids[i] = registry.id_of(name)
+        else:
+            ids[i], n = n, n + 1
+    return ids[inv.reshape(-1)], n
 
 
 def _base_diff(h: dict, schema, view_names, model) -> int:
-    view_ids = {schema.edge_label_id(v) for v in view_names}
-    name_of = schema.edge_labels.name_of
-    m = h["edge_alive"] & ~np.isin(h["edge_label"], list(view_ids))
-    got: Dict[tuple, int] = {}
-    for s, d, lab in zip(h["edge_src"][m], h["edge_dst"][m],
-                         h["edge_label"][m]):
-        key = (int(s), int(d), name_of(int(lab)))
-        got[key] = got.get(key, 0) + 1
-    nodes_got = {int(i): schema.node_labels.name_of(int(h["node_label"][i]))
-                 for i in np.flatnonzero(h["node_alive"])}
-    nodes_want = {i: lab for i, (lab, a) in enumerate(
-        zip(model.node_label, model.node_alive)) if a}
-    return (_dict_diff(got, model.edge_multiset())
-            + _dict_diff(nodes_got, nodes_want))
+    """Live base edges, as a multiset of (src, dst, label), and live nodes
+    with their labels that differ between the program and the model."""
+    n_slots = _n_slots(h, model)
+    view_ids = [schema.edge_label_id(v) for v in view_names]
+    m = h["edge_alive"] & ~np.isin(h["edge_label"], view_ids)
+    src, dst, names = model.edge_arrays()
+    lab, n_lab = _label_ids(schema.edge_labels, names)
+    lab_got = h["edge_label"][m].astype(np.int64)
+    if lab_got.size and (lab_got.min() < 0 or lab_got.max() >= n_lab):
+        raise ValueError(f"an edge label id lies outside [0, {n_lab})")
+    if n_slots ** 2 * n_lab > 2 ** 63:
+        raise ValueError("too many node slots and labels to key edges")
+    got = np.unique(ref_mod.pair_keys(h["edge_src"][m], h["edge_dst"][m],
+                                      n_slots) * n_lab + lab_got,
+                    return_counts=True)
+    want = np.unique(ref_mod.pair_keys(src, dst, n_slots) * n_lab + lab,
+                     return_counts=True)
+    alive_got = np.flatnonzero(h["node_alive"])
+    alive_want = np.flatnonzero(np.asarray(model.node_alive, bool))
+    nodes_want = _label_ids(schema.node_labels, np.asarray(
+        [model.node_label[i] for i in alive_want.tolist()], object))[0]
+    return (_key_diff(got, want)
+            + _key_diff((alive_got, h["node_label"][alive_got]
+                         .astype(np.int64)), (alive_want, nodes_want)))
 
 
 def _row_wrong(got: np.ndarray, want: np.ndarray, counting: bool) -> bool:
@@ -397,7 +447,7 @@ def compare(model0, log, samples, snapshots, paths, views, schema,
     snaps = {}
     for epoch, h in snapshots:
         snaps.setdefault(epoch, []).append(h)
-    rows_wrong = views_wrong = base_wrong = 0
+    rows_wrong = views_wrong = views_duplicated = base_wrong = 0
     for epoch in sorted(set(by_epoch) | set(snaps)):
         while version < epoch:
             replay(model, log[version][1])
@@ -416,13 +466,16 @@ def compare(model0, log, samples, snapshots, paths, views, schema,
         for h in snaps.get(epoch, []):
             base_wrong += _base_diff(h, schema, [v.name for v in views],
                                      model)
+            n_slots = _n_slots(h, model)
             for v in views:
-                stored = _view_pairs_stored(h, schema.edge_label_id(v.name),
-                                            v.path.counting)
-                views_wrong += _dict_diff(stored, ref.view_pairs(v))
+                *stored, dup = _view_pairs_stored(
+                    h, schema.edge_label_id(v.name), v.path.counting, n_slots)
+                views_wrong += _key_diff(stored, ref.view_pairs(v, n_slots))
+                views_duplicated += dup
     seen = {s[0] for s in samples}
     return {"read_rows_wrong": rows_wrong,
             "view_pairs_wrong": views_wrong,
+            "view_pairs_duplicated": views_duplicated,
             "base_items_wrong": base_wrong,
             "reads_unchecked": sum(1 for qi in reads_used if qi not in seen)}
 
@@ -446,13 +499,12 @@ def run_cell(name: str, cfg: dict, tr: dict, seed: int, seconds: float,
         rng_rows = [np.random.default_rng(c) for c in ss.spawn(7)]
     compiles = Compiles()
     spans = Spans(annotate=trace)
-    phases: Dict[str, float] = {}
     t_phase = time.perf_counter()
 
     def phase(name: str) -> None:
         nonlocal t_phase
         now = time.perf_counter()
-        phases[name] = now - t_phase
+        _log(f"set-up: {name} {now - t_phase:.2f} s")
         t_phase = now
 
     dataset = graphgen.generate(cfg["generator"], cfg["dataset_seed"], cfg)
@@ -535,6 +587,7 @@ def run_cell(name: str, cfg: dict, tr: dict, seed: int, seconds: float,
     loop.prime()
     w = tr["warm"]
     quiet = n_steps = 0
+    t_note = time.perf_counter()
     while True:
         c0 = compiles.n
         loop.step()
@@ -544,14 +597,18 @@ def run_cell(name: str, cfg: dict, tr: dict, seed: int, seconds: float,
         enough = (n_steps >= w["min_steps"] and quiet >= w["quiet_steps"]
                   and (writer is None or control is not None
                        or writer.passes >= 1))
-        if enough or time.perf_counter() - t_warm > w["max_s"]:
+        now = time.perf_counter()
+        if enough or now - t_warm > w["max_s"]:
             break
+        if now - t_note >= 10:
+            # so that a run stopped inside a long warm-up says where it was
+            t_note = now
+            _log(f"warm-up so far: {n_steps} steps, {now - t_warm:.1f} s, "
+                 f"quiet {quiet}, compile events {compiles.n - c_warm}")
     warm_s = time.perf_counter() - t_warm
     phase("warm.loop")
     _log(f"warm-up: {n_steps} steps, {warm_s:.2f} s, quiet {quiet}, "
          f"compile events {compiles.n - c_warm} (set-up {compiles.n})")
-    _log("set-up phases: " + ", ".join(f"{k} {v:.2f} s"
-                                       for k, v in phases.items()))
 
     # -- the window
     if writer is not None:
@@ -602,6 +659,11 @@ def run_cell(name: str, cfg: dict, tr: dict, seed: int, seconds: float,
               fences=d_fences, compiles_in_window=compiles_in_window,
               views_build_s=views_build_s, warm_s=warm_s, trace=summary)
 
+    _log(f"window: {t1 - t0:.3f} s, reads {len(loop.lat)}, "
+         f"windows {d_windows}, fences {d_fences}, compile events "
+         f"{compiles_in_window}, samples {len(loop.samples)}, "
+         f"states to compare {len(loop.snapshots) + 1}")
+
     # -- the comparison, with the program's state freed first
     t_ref = time.perf_counter()
     log = pre_log + (writer.log if writer is not None else [])
@@ -622,9 +684,7 @@ def run_cell(name: str, cfg: dict, tr: dict, seed: int, seconds: float,
         checks["write_kinds_unchecked"] = len(set(KINDS) - kinds_seen)
     ref_s = time.perf_counter() - t_ref
 
-    _log(f"window: {t1 - t0:.3f} s, reads {lat.size}, windows {d_windows}, "
-         f"fences {d_fences}, compile events {compiles_in_window}, "
-         f"reference {ref_s:.2f} s, samples {len(samples)}")
+    _log(f"reference {ref_s:.2f} s")
     if writer is not None and lat.size:
         _log(f"readers (not a metric): p50 {np.median(lat) * 1e3:.3f} ms, "
              f"p95 {np.percentile(lat, 95) * 1e3:.3f} ms")

@@ -205,6 +205,14 @@ class GraphModel:
             out[e] = out.get(e, 0) + 1
         return out
 
+    def edge_arrays(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Every live edge as ``src`` and ``dst`` (int64) and ``label``
+        (label names, an object array), in one order."""
+        es = list(self.edges.values())
+        return (np.fromiter((e[0] for e in es), np.int64, len(es)),
+                np.fromiter((e[1] for e in es), np.int64, len(es)),
+                np.asarray([e[2] for e in es], object))
+
 
 @dataclass
 class Reference:
@@ -220,10 +228,7 @@ class Reference:
         labels = np.asarray([lab or "" for lab in m.node_label], object)
         self._keep = {lab: alive & (labels == lab) for lab in set(labels)}
         self._alive = alive
-        es = list(m.edges.values())
-        self._src = np.asarray([e[0] for e in es], np.int64)
-        self._dst = np.asarray([e[1] for e in es], np.int64)
-        self._lab = np.asarray([e[2] for e in es], object)
+        self._src, self._dst, self._lab = m.edge_arrays()
 
     def adj(self, label: str, direction: str) -> sp.csr_matrix:
         key = (label, direction)
@@ -286,13 +291,32 @@ class Reference:
             X.eliminate_zeros()
         return X
 
-    def view_pairs(self, view: View) -> Dict[Tuple[int, int], int]:
-        """{(src, dst): count} of the view's edges (count 1 for set views)."""
+    def view_pairs(self, view: View, n_slots: int
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+        """The view's edges as sorted ``pair_keys`` and their counts (1 for
+        set views), both int64."""
         start = self.model.label_nodes(view.path.nodes[0][1])
         R = self.rows(view.path, start).tocoo()
         s = start[R.row]
         d = R.col.astype(np.int64)
-        c = R.data if view.path.counting else np.ones_like(R.data)
+        c = (R.data.astype(np.int64) if view.path.counting
+             else np.ones(R.data.shape[0], np.int64))
         if not view.forward:
             s, d = d, s
-        return {(int(a), int(b)): int(k) for a, b, k in zip(s, d, c)}
+        keys = pair_keys(s, d, n_slots)
+        order = np.argsort(keys, kind="stable")
+        return keys[order], c[order]
+
+
+def pair_keys(src, dst, n_slots: int) -> np.ndarray:
+    """One int64 key per (src, dst) pair, ``src * n_slots + dst``: no two
+    pairs share a key while every id lies in ``[0, n_slots)``, which is
+    checked."""
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    if n_slots ** 2 > 2 ** 63:
+        raise ValueError(f"{n_slots} node slots are too many to key pairs")
+    for ids in (src, dst):
+        if ids.size and (ids.min() < 0 or ids.max() >= n_slots):
+            raise ValueError(f"a node id lies outside [0, {n_slots})")
+    return src * n_slots + dst

@@ -4,7 +4,8 @@ import numpy as np
 import pytest
 
 from bench.lib import graphgen, reference as R
-from bench.lib.cell import _graph_host, _load, _row_wrong, _view_pairs_stored
+from bench.lib.cell import (_graph_host, _load, _n_slots, _row_wrong,
+                            _view_pairs_stored)
 from bench.tests import tiny
 
 
@@ -32,11 +33,15 @@ def test_reference_matches_program(cell):
                 assert not _row_wrong(row, w, path.counting), text
         assert want.any()
     h = _graph_host(sess.g)
+    n_slots = _n_slots(h, model)
     for text in cfg["views"]:
         v = R.parse_view(text)
-        stored = _view_pairs_stored(h, schema.edge_label_id(v.name),
-                                    v.path.counting)
-        assert stored == ref.view_pairs(v), v.name
+        keys, counts, duplicated = _view_pairs_stored(
+            h, schema.edge_label_id(v.name), v.path.counting, n_slots)
+        want_keys, want_counts = ref.view_pairs(v, n_slots)
+        assert duplicated == 0, v.name
+        assert np.array_equal(keys, want_keys), v.name
+        assert np.array_equal(counts, want_counts), v.name
 
 
 def test_parser_hop_ranges_and_directions():
